@@ -1,10 +1,13 @@
 """Distributed Contour (paper §IV-G analogue): shard_map weak-scaling dry
 measurement + the beyond-paper local-rounds trade.
 
-Runs in a subprocess with 8 interpreted host devices (the bench process
+Runs in a subprocess with 8 virtual CPU devices (the bench process
 itself keeps the real device count), reporting global rounds and
 collective bytes per convergence for local_rounds in {1, 2, 4} — the
-§Perf hillclimb lever for the contour-cc production cells.
+§Perf hillclimb lever for the contour-cc production cells.  The child is
+held to the CPU (``JAX_PLATFORMS=cpu``): it is a rehearsal of the mesh
+logic, its times are CPU times, and a parent that already holds the
+accelerator keeps it.
 """
 from __future__ import annotations
 
@@ -32,6 +35,8 @@ BODY = textwrap.dedent("""
         "grid_128": gen.grid2d(128, 128),
         "rmat_14": gen.rmat(14, seed=2),
     }
+    print(f"CPU rehearsal on {len(jax.devices())} virtual "
+          f"{jax.devices()[0].platform} devices; times are not chip times")
     print(f"{'graph':10s} {'lr':>3s} {'rounds':>7s} {'coll_MB/conv':>13s} "
           f"{'time_s':>8s}")
     for name, g in graphs.items():
@@ -55,6 +60,8 @@ def main(fast: bool = False):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    # one process per chip: the child never takes the accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", BODY], capture_output=True,
                          text=True, env=env, timeout=900)
     print(out.stdout)

@@ -23,8 +23,11 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
 import traceback
+
+import jax
 
 from benchmarks import (
     connectivity,
@@ -58,6 +61,12 @@ SECTIONS = [
     ("serving_engine", serving.main),
 ]
 
+# JAX's persistent compile cache when JAX_COMPILATION_CACHE_DIR is unset
+# (when it is set, JAX reads it itself): one fixed directory in the
+# checkout, so a later run finds what an earlier one compiled.
+CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), ".jax_cache")
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -79,6 +88,8 @@ def main() -> None:
                          "to restrict the strategy-matrix gate to; "
                          "default: all registered strategies + auto")
     args = ap.parse_args()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
 
     # Fail fast on an impossible backend request *before* any section
     # runs — a raw Pallas lowering error mid-suite helps nobody.

@@ -59,13 +59,13 @@ def candidate_plans(n_vertices: int, m_edges: int,
     for schedule in ("masked", "staged"):
         add(base.replace(compact_schedule=schedule))
     if platform == "tpu":
-        # tile-size neighbourhood of the prior (the one-hot combine cost
-        # is ∝ label_block·chunk; bin padding waste is ∝ blocks·chunk)
+        # tile-size neighbourhood of the prior, in shapes Mosaic compiles
+        # (the combine cost per update is ∝ label_block; bin padding
+        # waste is ∝ blocks·chunk)
         for lb in (1024, 2048, 4096):
-            for cu in (64, 128, 256):
-                if lb * cu <= 1 << 20:   # cap the one-hot buffer at 4 MiB
-                    add(base.replace(label_block=lb, chunk_updates=cu,
-                                     fuse_relabel=False))
+            for cu in (1024, 4096):
+                add(base.replace(label_block=lb, chunk_updates=cu,
+                                 fuse_relabel=False))
         if base.fuse_relabel:
             add(base.replace(fuse_relabel=False))
     return cands

@@ -27,6 +27,13 @@ STAGED_MIN_EDGES = 1 << 15
 # materialisation, no radix binning).
 SINGLE_TILE_MAX_N = 4096
 
+# TPU tile unit of the blocked and fused kernels: one (8, 128) int32 vreg
+# of labels, and XLA's tiling of 1-D int32 arrays in HBM (update chunks).
+TPU_TILE = 1024
+# Label bins the blocked kernel's SMEM chunk map holds comfortably (half
+# of blocked.SMEM_MAP_MAX_CHUNKS; the stream's chunks take the rest).
+MAX_LABEL_BINS = 1 << 16
+
 # Out-of-core chunk sizing: per-edge device cost of one resident chunk.
 # A chunk holds int64 src/dst (16 B/edge) double-buffered (32 B/edge),
 # plus the sweep's relabeled copies and contraction temporaries — call it
@@ -47,16 +54,17 @@ def heuristic_plan(
     """Pick backend + tile sizes + schedule for a graph size, by table.
 
     Off-TPU the only compilable backend is XLA scatter-min.  On TPU the
-    blocked kernel is always eligible (no ceiling); tile sizes balance the
-    one-hot combine work (∝ ``label_block`` per update) against per-bin
-    padding waste (∝ ``n_blocks·chunk_updates``):
+    blocked kernel is always eligible (no ceiling); its tiles are the
+    smallest shapes Mosaic compiles densely:
 
-    * small graphs waste least with one or two tiles spanning all of L —
-      and in the single-tile regime the fused relabel+scatter-min pass
-      skips the update-stream materialisation entirely;
-    * large graphs hold ``label_block`` at 2048 (8 KiB tile, 1 MiB one-hot
-      buffer at chunk 128) and scale ``chunk_updates`` with edge density
-      so sparse bins do not drown in padding.
+    * small graphs (n <= :data:`SINGLE_TILE_MAX_N`) hold all of L in one
+      tile, where the fused relabel+scatter-min pass skips the
+      update-stream materialisation entirely (L in SMEM);
+    * large graphs use 1024-slot label tiles — one (8, 128) int32 vreg,
+      so each update costs one vector compare/select/min — grown only
+      when the bin count would overflow the kernel's SMEM chunk map.
+      Chunks are 1024 updates (XLA's 1-D int32 HBM tiling); the kernel
+      doubles them if the stream's chunk map would overflow SMEM.
 
     ``compact_schedule`` only matters when the caller also enables the
     work-adaptive frontier (``sampling``/``compact_every``): big edge
@@ -71,24 +79,19 @@ def heuristic_plan(
         return ExecutionPlan(backend="xla", interpret=True,
                              compact_schedule=compact, origin="heuristic")
     if n_vertices <= SINGLE_TILE_MAX_N:
-        # single tile: the blocked kernel degenerates to a whole-L
-        # vectorized sweep with zero binning waste, and the fused
-        # gather+scatter-min pass applies
-        label_block = max(256, _round_up(n_vertices, 128))
-        chunk = 128
+        label_block = _round_up(max(n_vertices, 1), TPU_TILE)
         fuse = True
     else:
-        label_block = 2048
-        # denser update streams amortise more padding; cap the one-hot
-        # buffer at chunk*label_block = 512Ki elements (2 MiB)
-        chunk = 64 if n_edges < 8 * n_vertices else 256
+        # at most MAX_LABEL_BINS bins keep the chunk map within SMEM
+        label_block = max(TPU_TILE,
+                          next_pow2(-(-n_vertices // MAX_LABEL_BINS)))
         fuse = False
     block_edges = 512 if n_edges < 1 << 20 else 2048
     return ExecutionPlan(
         backend="pallas_blocked",
         block_edges=block_edges,
         label_block=label_block,
-        chunk_updates=chunk,
+        chunk_updates=TPU_TILE,
         interpret=False,
         compact_schedule=compact,
         fuse_relabel=fuse,

@@ -67,8 +67,8 @@ class ExecutionPlan:
 
     backend: str                    # concrete: "xla"|"pallas"|"pallas_blocked"
     block_edges: int = 512          # edge block of the scalar pallas kernel
-    label_block: int = 2048         # L tile height of the blocked kernel
-    chunk_updates: int = 128        # update-stream chunk of the blocked kernel
+    label_block: int = 1024         # L tile height of the blocked kernel
+    chunk_updates: int = 1024       # update-stream chunk of the blocked kernel
     interpret: bool = False         # Pallas interpreter mode (CPU validation)
     compact_schedule: str = "masked"  # frontier realisation: masked | staged
     fuse_relabel: bool = False      # single-tile fused gather+scatter-min pass

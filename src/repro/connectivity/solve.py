@@ -190,10 +190,10 @@ def solve(
             provenance.append(plan.provenance_entry())
     except Exception as exc:
         # Graceful degradation (DESIGN.md §12): a failed non-XLA kernel
-        # launch (Pallas lowering/compile/launch error on a host without
-        # the toolchain) falls back to the XLA reference path instead of
-        # failing the request.  Caller bugs (ValueError/TypeError/...)
-        # and injected SimulatedFaults propagate untouched.
+        # launch falls back to the XLA reference path instead of failing
+        # the request.  Caller bugs (ValueError/TypeError/...), kernels
+        # that fail to lower or compile, and injected SimulatedFaults
+        # propagate untouched.
         backend = (plan.backend if plan is not None
                    and opts.backend == "auto" else opts.backend)
         if (not opts.kernel_fallback or backend == "xla"

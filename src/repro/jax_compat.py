@@ -1,44 +1,27 @@
-"""Version tolerance for the small jax API surface this repo leans on.
+"""The small jax mesh/sharding API surface this repo leans on, in one place.
 
-The codebase targets the current mesh/shard_map API (``jax.shard_map``,
+The codebase targets the current API (``jax.shard_map``,
 ``jax.sharding.AxisType``, ``AbstractMesh(sizes, names)``, dict-valued
-``compiled.cost_analysis()``).  The baked accelerator toolchain may ship an
-older jax where those live under experimental names or older signatures
-(e.g. 0.4.x: ``jax.experimental.shard_map``, no ``AxisType``,
-``AbstractMesh(((name, size), ...))``, list-valued ``cost_analysis``).
-Importing the symbols from here keeps every call site version-agnostic —
-and keeps the whole distributed/sharding layer *runnable* instead of
-failing on import-time attribute errors.
+``compiled.cost_analysis()``).  These helpers give every call site the
+same Auto-typed meshes and one import for ``shard_map``.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Sequence
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax < 0.5: experimental home, whose static replication checker
-    # predates a `while` rule — disable it (validation only, not semantics)
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, **kwargs):
-        kwargs.setdefault("check_rep", False)
-        return _shard_map_legacy(f, **kwargs)
+shard_map = jax.shard_map
 
 
 def mesh_axis_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto, ...)`` where supported; ``{}`` on older jax
-    (whose meshes behave as Auto for shard_map/jit purposes anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return {"axis_types": (axis_type.Auto,) * n_axes}
-    return {}
+    """``axis_types=(Auto, ...)`` for a mesh with ``n_axes`` axes."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_mesh(axis_shapes: Sequence[int],
               axis_names: Sequence[str]) -> jax.sharding.Mesh:
-    """`jax.make_mesh` with Auto axis types where the kwarg exists."""
+    """`jax.make_mesh` with Auto axis types."""
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
                          **mesh_axis_kwargs(len(axis_names)))
 
@@ -50,22 +33,11 @@ def device_mesh(devices, axis_names: Sequence[str]) -> jax.sharding.Mesh:
 
 
 def abstract_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]):
-    """`AbstractMesh` across the signature change.
-
-    Current jax: ``AbstractMesh(axis_sizes, axis_names)``; 0.4.x:
-    ``AbstractMesh(shape_tuple)`` with (name, size) pairs.
-    """
+    """`AbstractMesh` over the given axis sizes and names."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def cost_analysis(compiled) -> dict:
-    """Dict-valued ``compiled.cost_analysis()`` on every jax version
-    (0.4.x returned a one-element list of dicts)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``compiled.cost_analysis()`` as a plain dict."""
+    return dict(compiled.cost_analysis())

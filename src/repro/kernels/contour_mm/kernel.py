@@ -23,9 +23,13 @@ TPU adaptation notes (DESIGN.md §3):
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.contour_mm.blocked import resolve_interpret
 
 
 def _mm2_kernel(src_ref, dst_ref, l_in_ref, l_ref):
@@ -51,16 +55,19 @@ def _mm2_kernel(src_ref, dst_ref, l_in_ref, l_ref):
 
 
 def mm2_pallas(src: jax.Array, dst: jax.Array, L: jax.Array,
-               *, block_edges: int = 512, interpret: bool = True) -> jax.Array:
+               *, block_edges: int = 512,
+               interpret: Optional[bool] = None) -> jax.Array:
     """One full asynchronous 2-order sweep over all edges; returns new L.
 
     Args:
       src, dst: int32[m] edge endpoints; m must be a multiple of
         ``block_edges`` (pad with self-loops, which are MM no-ops).
       L: int32[n] current labels.
-      interpret: run the kernel body in interpret mode (CPU validation);
-        pass False on real TPU hardware.
+      interpret: run the kernel body in interpret mode; default: True
+        off-TPU.  Compiled for TPU, Mosaic refuses the scalar VMEM stores
+        (the ops layer raises before lowering).
     """
+    interpret = resolve_interpret(interpret)
     m = src.shape[0]
     if m % block_edges != 0:
         raise ValueError(f"m={m} must be a multiple of block_edges={block_edges}")

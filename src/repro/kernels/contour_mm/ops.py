@@ -8,7 +8,8 @@ Three device backends realise the same MM^h sweep (DESIGN.md §3):
 * ``"pallas"``        — the seed fused in-VMEM asynchronous kernel
   (`kernel.mm2_pallas`): whole ``L`` VMEM-resident (ceiling n ≈ 3M),
   scalar sequential inner loop, 2-order only.  Kept as the
-  deterministic-async reference.
+  deterministic-async reference; interpret mode only — it does not
+  compile for TPU, so a compiled request raises ``ValueError``.
 * ``"pallas_blocked"`` — the label-blocked vectorized kernel
   (`blocked.binned_scatter_min_pallas`): edges are reduced to an update
   stream, radix-binned by ``target // label_block`` on device, and one
@@ -74,8 +75,8 @@ class KernelPlan:
 
     backend: str                # concrete: "xla" | "pallas" | "pallas_blocked"
     block_edges: int = 512      # edge block of the scalar pallas kernel
-    label_block: int = 2048     # L tile height of the blocked kernel
-    chunk_updates: int = 128    # update-stream chunk of the blocked kernel
+    label_block: int = 1024     # L tile height of the blocked kernel
+    chunk_updates: int = 1024   # update-stream chunk of the blocked kernel
     interpret: bool = False     # Pallas interpreter mode (CPU validation)
 
 
@@ -186,6 +187,11 @@ def mm_relax_backend(
             dst = jnp.where(edge_mask, dst, 0)
         return lab.mm_relax(L, src, dst, order)
     if backend == "pallas":
+        if not interpret:
+            raise ValueError(
+                "the scalar 'pallas' kernel does not compile for TPU "
+                "(Mosaic cannot store scalars to VMEM); use "
+                "'pallas_blocked' or 'xla'")
         if order != 2:
             raise ValueError(
                 "the scalar 'pallas' kernel is 2-order only; use "

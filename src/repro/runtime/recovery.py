@@ -21,6 +21,8 @@ import dataclasses
 import time
 from typing import Any, Callable, Dict, Optional, Tuple, Type, Union
 
+import jax
+
 from repro.checkpoint.manager import CheckpointManager
 
 
@@ -56,17 +58,38 @@ NON_TRANSIENT_ERRORS: Tuple[Type[BaseException], ...] = (
     ValueError, TypeError, KeyError, IndexError, NotImplementedError)
 
 
+# Pallas/Mosaic lowering and compile failures (matched by class name:
+# the classes live in private jax modules).  A kernel that does not
+# compile is a bug in the program, and rerunning on XLA would hide it.
+_KERNEL_BUILD_ERRORS = ("MosaicError", "VerificationError",
+                        "LoweringException")
+# A JaxRuntimeError raised by the TPU compiler for a Mosaic kernel names
+# the kernel, or the kernel's SMEM/VMEM allocation, in its message.
+_KERNEL_BUILD_MARKERS = ("Mosaic", "space=smem", "space=vmem")
+
+
+def _is_kernel_build_error(exc: BaseException) -> bool:
+    """True iff ``exc`` is a kernel lowering or compile failure."""
+    if any(c.__name__ in _KERNEL_BUILD_ERRORS for c in type(exc).__mro__):
+        return True
+    return (isinstance(exc, jax.errors.JaxRuntimeError)
+            and any(k in str(exc) for k in _KERNEL_BUILD_MARKERS))
+
+
 def is_transient_error(exc: BaseException) -> bool:
     """True iff ``exc`` plausibly came from the machine, not the caller.
 
     Used by the kernel-fallback path (``solve()`` / streaming ingest) to
     decide whether a failed Pallas launch is worth retrying on the XLA
-    reference backend: runtime/compile errors are; argument-validation
-    errors and injected :class:`SimulatedFault`\\ s are not.
+    reference backend: runtime launch errors are; argument-validation
+    errors, kernel lowering/compile failures and injected
+    :class:`SimulatedFault`\\ s are not.
     """
     if isinstance(exc, SimulatedFault):
         return False
     if isinstance(exc, NON_TRANSIENT_ERRORS):
+        return False
+    if _is_kernel_build_error(exc):
         return False
     return isinstance(exc, Exception)
 

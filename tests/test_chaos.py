@@ -320,6 +320,62 @@ def test_solve_fallback_never_masks_caller_bugs():
         _REGISTRY.pop("faulty", None)
 
 
+def _mosaic_error():
+    from jax._src.pallas.mosaic.error_handling import MosaicError
+    return MosaicError("infer-vector-layout: unsupported shape cast")
+
+
+def _mosaic_compile_error():
+    import jax
+    return jax.errors.JaxRuntimeError(
+        "INVALID_ARGUMENT: Mosaic failed to compile TPU kernel: Failed to "
+        "verify layout for Mosaic kernel operand 3")
+
+
+@pytest.mark.parametrize("make_exc", [_mosaic_error, _mosaic_compile_error],
+                         ids=["lowering", "compile"])
+def test_solve_kernel_build_failure_is_not_retried(make_exc):
+    """A kernel that fails to lower or compile is a program bug: even with
+    kernel_fallback on, solve() raises instead of rerunning on XLA."""
+    base = get_solver("contour")
+    calls = []
+
+    def broken_fn(graph, opts, init):
+        calls.append(opts.backend)
+        if opts.backend != "xla":
+            raise make_exc()
+        return _contour_solver(graph, opts, init)
+
+    from repro.connectivity.registry import _REGISTRY
+    g = gen.path(50, seed=1)
+    try:
+        register_solver(dataclasses.replace(base, name="broken",
+                                            fn=broken_fn, aliases=()))
+        with pytest.raises(type(make_exc())):
+            solve(g, algorithm="broken", backend="pallas_blocked",
+                  kernel_fallback=True)
+    finally:
+        _REGISTRY.pop("broken", None)
+    assert calls == ["pallas_blocked"]  # no XLA rerun
+
+
+def test_streaming_kernel_build_failure_is_not_retried(monkeypatch):
+    g, _, batches = _stream_fixture(n_batches=2)
+    real = streaming_mod.delta_converge
+
+    def fake(*args, **kw):
+        if kw.get("backend") != "xla":
+            raise _mosaic_compile_error()
+        return real(*args, **kw)
+
+    monkeypatch.setattr(streaming_mod, "delta_converge", fake)
+    eng = StreamingConnectivity(g.n_vertices,
+                                SolveOptions(backend="pallas_blocked"))
+    with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+        eng.ingest(*batches[0])
+    assert eng.n_edges == 0
+
+
 def test_streaming_kernel_fallback(monkeypatch):
     g, oracle, batches = _stream_fixture(n_batches=4)
     real = streaming_mod.delta_converge

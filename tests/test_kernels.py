@@ -158,7 +158,9 @@ def test_dispatch_plan():
 
     big = heuristic_plan(50_000_000, 800_000_000, platform="tpu")
     assert big.backend == "pallas_blocked"  # no vertex ceiling
-    # one-hot combine buffer stays within a VMEM-friendly budget
+    # tiles Mosaic compiles densely: whole (8, 128) int32 vregs of labels,
+    # chunks in XLA's 1024-element tiling of 1-D int32 arrays
+    assert big.label_block % 1024 == 0 and big.chunk_updates % 1024 == 0
     assert big.label_block * big.chunk_updates * 4 <= 4 * 1024 * 1024
     assert not big.fuse_relabel             # multi-tile: binned pipeline
 
@@ -178,6 +180,38 @@ def test_scalar_pallas_vmem_ceiling_enforced():
     dst = jnp.ones((4,), jnp.int32)
     with pytest.raises(ValueError, match="ceiling"):
         mm_relax_backend(L, src, dst, backend="pallas")
+
+
+def test_scalar_pallas_refused_when_compiled():
+    """The scalar kernel does not compile for TPU: a compiled request is
+    refused before lowering."""
+    from repro.kernels.contour_mm.ops import mm_relax_backend
+
+    L = jnp.arange(8, dtype=jnp.int32)
+    src = jnp.zeros((4,), jnp.int32)
+    with pytest.raises(ValueError, match="does not compile for TPU"):
+        mm_relax_backend(L, src, src + 1, backend="pallas", interpret=False)
+
+
+def test_kernel_entry_points_follow_platform():
+    """Without ``interpret`` the kernels pick interpreter mode off-TPU (and
+    compile on a TPU); either way a sweep equals the scatter-min oracle."""
+    from repro.core import labels as lab
+    from repro.kernels.contour_mm.blocked import (binned_scatter_min_pallas,
+                                                  fused_relax_pallas)
+    from repro.kernels.contour_mm.kernel import mm2_pallas
+    from repro.kernels.contour_mm.ops import _pad_edges
+    from repro.kernels.contour_mm.ref import mm_block_ref
+
+    g = gen.rmat(9, seed=4)
+    L = jnp.arange(g.n_vertices, dtype=jnp.int32)
+    ref = np.asarray(lab.mm_relax(L, g.src, g.dst, order=2))
+    t, v = lab.mm_update_stream(L, g.src, g.dst, 2)
+    assert (np.asarray(binned_scatter_min_pallas(L, t, v)) == ref).all()
+    assert (np.asarray(fused_relax_pallas(L, g.src, g.dst)) == ref).all()
+    src_p, dst_p = _pad_edges(g.src, g.dst, 512)
+    assert (np.asarray(mm2_pallas(src_p, dst_p, L))
+            == np.asarray(mm_block_ref(src_p, dst_p, L))).all()
 
 
 def test_auto_backend_step_matches_mm_relax():

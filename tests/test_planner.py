@@ -63,7 +63,7 @@ def test_heuristic_plan_is_platform_and_size_aware():
     assert small.backend == "pallas_blocked"
     assert small.fuse_relabel and small.label_block >= 1000
     big = heuristic_plan(1 << 20, 1 << 22, "tpu")
-    assert not big.fuse_relabel and big.label_block == 2048
+    assert not big.fuse_relabel and big.label_block == 1024
     assert heuristic_plan(100, 100, "tpu").compact_schedule == "masked"
     assert heuristic_plan(100, 1 << 16, "tpu").compact_schedule == "staged"
 
