@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import jax_compat
+from repro import jax_compat, tracing
 from repro.connectivity import frontier as fr
 from repro.connectivity import minmap as lab
 from repro.graphs.structs import Graph
@@ -134,7 +134,8 @@ def _distributed_fixpoint(
                                             edge_limit=limit, **tile_kw)
                 L = lab.pointer_jump(L, rounds=async_compress)
             # the one collective of the round: elementwise min across shards
-            return jax.lax.pmin(L, axis)
+            with jax.named_scope(tracing.LABEL_ALLREDUCE):
+                return jax.lax.pmin(L, axis)
 
         def all_shards_ok(ok_local):
             return jnp.bool_(jax.lax.pmin(ok_local.astype(jnp.int32), axis))
@@ -309,7 +310,8 @@ def distributed_contour_step_fn(
                 L = mm_ops.mm_relax_backend(L, src_loc, dst_loc, order=2,
                                             backend=backend)
                 L = lab.pointer_jump(L, rounds=1)
-            L = jax.lax.pmin(L, axis)
+            with jax.named_scope(tracing.LABEL_ALLREDUCE):
+                L = jax.lax.pmin(L, axis)
             if check_every <= 1:
                 ok_local = lab.converged_early(L, src_loc, dst_loc)
                 ok = jnp.bool_(jax.lax.pmin(ok_local.astype(jnp.int32), axis))

@@ -69,6 +69,7 @@ from typing import Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.connectivity import minmap as lab
 
 # The deterministic sampling prefix is m // SAMPLE_PREFIX_DENOM edges
@@ -237,7 +238,9 @@ def prepare_sampling(name: str, src: jax.Array, dst: jax.Array,
     prefix; returns ``(src', dst', sample_m)``."""
     if k < 1:
         raise ValueError(f"sampling k must be >= 1, got {k}")
-    return get_sampling_strategy(name).prepare(src, dst, n_vertices, k)
+    prepare = get_sampling_strategy(name).prepare
+    with jax.named_scope(tracing.SAMPLING_PREP):
+        return prepare(src, dst, n_vertices, k)
 
 
 def largest_component_label(L: jax.Array, n_vertices: int) -> jax.Array:
@@ -299,10 +302,11 @@ def masked_converged_early(
     the ``active_m``-edge prefix can still violate the predicate; with an
     empty frontier the solve is converged by definition.
     """
-    pos = jnp.arange(src.shape[0], dtype=active_m.dtype)
-    lw, lv = L[src], L[dst]
-    bad = (lw != lv) | (lw != L[lw]) | (lv != L[lv])
-    return ~jnp.any(bad & (pos < active_m))
+    with jax.named_scope(tracing.CONVERGE_CHECK):
+        pos = jnp.arange(src.shape[0], dtype=active_m.dtype)
+        lw, lv = L[src], L[dst]
+        bad = (lw != lv) | (lw != L[lw]) | (lv != L[lv])
+        return ~jnp.any(bad & (pos < active_m))
 
 
 def frontier_limit(it: jax.Array, active_m: jax.Array, sample_m: jax.Array,
@@ -381,8 +385,10 @@ def apply_compaction(
         return contract_edges(lbl, e_src, e_dst, am)
 
     idx = jnp.where(do_lc, 1, jnp.where(do_gen, 2, 0))
-    return jax.lax.switch(idx, [no_compact, compact_largest, compact_general],
-                          (L, src, dst, active_m))
+    with jax.named_scope(tracing.COMPACT):
+        return jax.lax.switch(
+            idx, [no_compact, compact_largest, compact_general],
+            (L, src, dst, active_m))
 
 
 def compress_full(L: jax.Array) -> jax.Array:
@@ -394,11 +400,12 @@ def compress_full(L: jax.Array) -> jax.Array:
     checked), so the adaptive path compresses to the fixed point — the
     O(log depth) rounds run once, after the main loop.
     """
-    return jax.lax.while_loop(
-        lambda lbl: ~lab.is_star_forest(lbl),
-        lambda lbl: lab.pointer_jump(lbl, rounds=1),
-        L,
-    )
+    with jax.named_scope(tracing.COMPRESS):
+        return jax.lax.while_loop(
+            lambda lbl: ~lab.is_star_forest(lbl),
+            lambda lbl: lab.pointer_jump(lbl, rounds=1),
+            L,
+        )
 
 
 class FrontierState(NamedTuple):

@@ -23,6 +23,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+
 
 def gather_chain(L: jax.Array, idx: jax.Array, order: int) -> Tuple[jax.Array, ...]:
     """Return (L^1[idx], ..., L^order[idx])."""
@@ -80,9 +82,10 @@ def pointer_jump(L: jax.Array, rounds: int = 1) -> jax.Array:
     high-order ``C-m`` operator without length-m serial gather chains
     (DESIGN.md §3).
     """
-    for _ in range(rounds):
-        L = jnp.minimum(L, L[L])
-    return L
+    with jax.named_scope(tracing.POINTER_JUMP):
+        for _ in range(rounds):
+            L = jnp.minimum(L, L[L])
+        return L
 
 
 def converged_early(L: jax.Array, src: jax.Array, dst: jax.Array) -> jax.Array:
@@ -91,9 +94,10 @@ def converged_early(L: jax.Array, src: jax.Array, dst: jax.Array) -> jax.Array:
     Converged iff for every edge (w, v):
         L[w] == L[v]  and  L[w] == L^2[w]  and  L[v] == L^2[v].
     """
-    lw, lv = L[src], L[dst]
-    bad = (lw != lv) | (lw != L[lw]) | (lv != L[lv])
-    return ~jnp.any(bad)
+    with jax.named_scope(tracing.CONVERGE_CHECK):
+        lw, lv = L[src], L[dst]
+        bad = (lw != lv) | (lw != L[lw]) | (lv != L[lv])
+        return ~jnp.any(bad)
 
 
 def is_star_forest(L: jax.Array) -> jax.Array:

@@ -27,11 +27,13 @@ Example::
 """
 from __future__ import annotations
 
+import itertools
 from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.connectivity import minmap
 from repro.connectivity import planner as _planner
 from repro.connectivity.options import SolveOptions
@@ -45,6 +47,9 @@ from repro.graphs.structs import Graph
 # "oocore" additionally gets the VMEM-derived streaming chunk bucket
 # stamped into the plan (solvers.resolve_backend_plan).
 _PLANNED_SOLVERS = ("contour", "distributed", "oocore")
+
+# numbers the solve() calls of the process in their trace spans
+_SOLVE_CALLS = itertools.count()
 
 
 def resolve_warm_start(warm_start, n_vertices: int):
@@ -166,60 +171,67 @@ def solve(
       predicate for the min-mapping family; False iff the ``max_iters``
       budget ran out first).
     """
-    opts, spec = _resolve(options, overrides)
-    init = resolve_warm_start(
-        warm_start if warm_start is not None else opts.warm_start,
-        graph.n_vertices)
-    if init is not None and not spec.supports_warm_start:
-        raise ValueError(f"solver {spec.name!r} does not support warm "
-                         "starts")
-    plan = None
-    if spec.name in _PLANNED_SOLVERS:
-        # Resolve the execution plan once at the facade (pinned > tuning
-        # cache for "auto" > heuristic tables) and pin it into the options
-        # so the solver, the provenance record and any retry all see the
-        # same plan.
-        from repro.connectivity.solvers import resolve_backend_plan
-        _, plan = resolve_backend_plan(graph.n_vertices, graph.n_edges,
-                                       opts)
-        opts = opts.replace(plan=plan)
-    provenance = []
-    try:
-        out = spec.fn(graph, opts, init)
-        if plan is not None:
-            provenance.append(plan.provenance_entry())
-    except Exception as exc:
-        # Graceful degradation (DESIGN.md §12): a failed non-XLA kernel
-        # launch falls back to the XLA reference path instead of failing
-        # the request.  Caller bugs (ValueError/TypeError/...), kernels
-        # that fail to lower or compile, and injected SimulatedFaults
-        # propagate untouched.
-        backend = (plan.backend if plan is not None
-                   and opts.backend == "auto" else opts.backend)
-        if (not opts.kernel_fallback or backend == "xla"
-                or spec.runs_on != "device" or not is_transient_error(exc)):
-            raise
-        try:
-            # demote this size bucket to XLA in the tuning cache — with a
-            # TTL, so the failed backend is retried/retuned later instead
-            # of being pinned out forever
-            _planner.record_kernel_failure(
-                graph.n_vertices, graph.n_edges, failed_backend=backend)
-        except Exception:
-            pass  # a cache write must never break the degradation path
-        retry_opts = opts.replace(backend="xla", plan=None)
-        out = spec.fn(graph, retry_opts, init)
-        provenance.append(
-            f"kernel_fallback:{backend}->xla "
-            f"({type(exc).__name__}: {str(exc)[:120]})")
+    with jax.profiler.TraceAnnotation(tracing.SOLVE,
+                                      call=next(_SOLVE_CALLS)):
+        opts, spec = _resolve(options, overrides)
+        init = resolve_warm_start(
+            warm_start if warm_start is not None else opts.warm_start,
+            graph.n_vertices)
+        if init is not None and not spec.supports_warm_start:
+            raise ValueError(f"solver {spec.name!r} does not support warm "
+                             "starts")
+        plan = None
         if spec.name in _PLANNED_SOLVERS:
+            # Resolve the execution plan once at the facade (pinned >
+            # tuning cache for "auto" > heuristic tables) and pin it into
+            # the options so the solver, the provenance record and any
+            # retry all see the same plan.
             from repro.connectivity.solvers import resolve_backend_plan
-            _, retry_plan = resolve_backend_plan(
-                graph.n_vertices, graph.n_edges, retry_opts)
+            with jax.profiler.TraceAnnotation(tracing.SOLVE_PLAN):
+                _, plan = resolve_backend_plan(graph.n_vertices, graph.n_edges,
+                                               opts)
+            opts = opts.replace(plan=plan)
+        provenance = []
+        try:
+            with jax.profiler.TraceAnnotation(tracing.SOLVE_DISPATCH):
+                out = spec.fn(graph, opts, init)
+            if plan is not None:
+                provenance.append(plan.provenance_entry())
+        except Exception as exc:
+            # Graceful degradation (DESIGN.md §12): a failed non-XLA kernel
+            # launch falls back to the XLA reference path instead of failing
+            # the request.  Caller bugs (ValueError/TypeError/...), kernels
+            # that fail to lower or compile, and injected SimulatedFaults
+            # propagate untouched.
+            backend = (plan.backend if plan is not None
+                       and opts.backend == "auto" else opts.backend)
+            if (not opts.kernel_fallback or backend == "xla"
+                    or spec.runs_on != "device"
+                    or not is_transient_error(exc)):
+                raise
+            try:
+                # demote this size bucket to XLA in the tuning cache — with a
+                # TTL, so the failed backend is retried/retuned later instead
+                # of being pinned out forever
+                _planner.record_kernel_failure(
+                    graph.n_vertices, graph.n_edges, failed_backend=backend)
+            except Exception:
+                pass  # a cache write must never break the degradation path
+            retry_opts = opts.replace(backend="xla", plan=None)
+            with jax.profiler.TraceAnnotation(tracing.SOLVE_DISPATCH):
+                out = spec.fn(graph, retry_opts, init)
             provenance.append(
-                retry_plan.replace(origin="fallback").provenance_entry())
-    labels, iterations, converged, edges_visited = solver_output(out)
-    if len(out) > 4 and out[4]:
-        provenance.extend(out[4])
-    return make_result(labels, iterations, converged, edges_visited,
-                       provenance=provenance)
+                f"kernel_fallback:{backend}->xla "
+                f"({type(exc).__name__}: {str(exc)[:120]})")
+            if spec.name in _PLANNED_SOLVERS:
+                from repro.connectivity.solvers import resolve_backend_plan
+                _, retry_plan = resolve_backend_plan(
+                    graph.n_vertices, graph.n_edges, retry_opts)
+                provenance.append(
+                    retry_plan.replace(origin="fallback").provenance_entry())
+        with jax.profiler.TraceAnnotation(tracing.SOLVE_RESULT):
+            labels, iterations, converged, edges_visited = solver_output(out)
+            if len(out) > 4 and out[4]:
+                provenance.extend(out[4])
+            return make_result(labels, iterations, converged, edges_visited,
+                               provenance=provenance)

@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.connectivity import distributed as dist
 from repro.connectivity import frontier as fr
 from repro.connectivity import minmap as lab
@@ -126,8 +127,9 @@ def delta_converge(
     carries its own labels and batch; ``n_active`` may differ per lane).
     """
     # supervertex rewrite: labels is a star forest, so L[u] is u's root
-    src = labels[src]
-    dst = labels[dst]
+    with jax.named_scope(tracing.SUPERVERTEX_REWRITE):
+        src = labels[src]
+        dst = labels[dst]
     step = _make_step(variant, warmup, async_compress, backend, plan)
     L, it, done, _, visited = fr.adaptive_fixpoint(
         src, dst, labels, step,
@@ -142,10 +144,11 @@ def delta_converge(
 @functools.partial(jax.jit, static_argnames=("pad_k",))
 def _pad_batch(src: jax.Array, dst: jax.Array, pad_k: int):
     """Pad a batch to its bucket size with self-loop no-op edges."""
-    k = src.shape[0]
-    fill = jnp.zeros((pad_k - k,), jnp.int32)
-    return (jnp.concatenate([src.astype(jnp.int32), fill]),
-            jnp.concatenate([dst.astype(jnp.int32), fill]))
+    with jax.named_scope(tracing.PAD):
+        k = src.shape[0]
+        fill = jnp.zeros((pad_k - k,), jnp.int32)
+        return (jnp.concatenate([src.astype(jnp.int32), fill]),
+                jnp.concatenate([dst.astype(jnp.int32), fill]))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -157,7 +160,8 @@ def _ring_write(buf: jax.Array, chunk: jax.Array, offset: jax.Array):
     result, so the append updates in place instead of copying the whole
     capacity every batch.
     """
-    return jax.lax.dynamic_update_slice(buf, chunk, (offset,))
+    with jax.named_scope(tracing.STORE):
+        return jax.lax.dynamic_update_slice(buf, chunk, (offset,))
 
 
 class StreamingConnectivity:
@@ -376,65 +380,74 @@ class StreamingConnectivity:
 
         Returns ``self`` (chainable).
         """
-        # keep device input on device (no pull unless validating); lift
-        # everything else to NumPy so validation is a pure host check
-        if not isinstance(src, jax.Array):
-            src = np.asarray(src)
-        if not isinstance(dst, jax.Array):
-            dst = np.asarray(dst)
-        if np.shape(src) != np.shape(dst) or len(np.shape(src)) != 1:
-            raise ValueError(
-                f"src/dst must be equal-length 1-D, got {np.shape(src)} "
-                f"vs {np.shape(dst)}")
-        old_n = self._n
-        if n_vertices is not None:
-            self._grow_vertices(int(n_vertices))
-        k = int(np.shape(src)[0])
-        if k == 0:
+        with jax.profiler.TraceAnnotation(tracing.INGEST,
+                                          batch=self._n_batches):
+            # keep device input on device (no pull unless validating); lift
+            # everything else to NumPy so validation is a pure host check
+            if not isinstance(src, jax.Array):
+                src = np.asarray(src)
+            if not isinstance(dst, jax.Array):
+                dst = np.asarray(dst)
+            if np.shape(src) != np.shape(dst) or len(np.shape(src)) != 1:
+                raise ValueError(
+                    f"src/dst must be equal-length 1-D, got {np.shape(src)} "
+                    f"vs {np.shape(dst)}")
+            old_n = self._n
+            if n_vertices is not None:
+                self._grow_vertices(int(n_vertices))
+            k = int(np.shape(src)[0])
+            if k == 0:
+                return self
+            if validate:
+                with jax.profiler.TraceAnnotation(tracing.INGEST_VALIDATE):
+                    self._validate_batch(np.asarray(src), np.asarray(dst))
+
+            pad_k = next_pow2(k)
+            with jax.profiler.TraceAnnotation(tracing.INGEST_PAD):
+                src_p, dst_p = _pad_batch(jnp.asarray(src, jnp.int32),
+                                          jnp.asarray(dst, jnp.int32), pad_k)
+
+            # delta re-convergence: sweep only the new batch, warm-started.
+            # Everything up to the scalar commit below runs inside the
+            # rollback guard — vertex growth rolls back on failure (surplus
+            # label capacity is invisible identity padding) and ring writes
+            # only ever touch slots >= _m, which no reader observes — so a
+            # failure anywhere (backend compile error, OOM at a new bucket
+            # size, an injected crash after the ring write) leaves the
+            # engine exactly as it was: ingest is atomic.
+            try:
+                if self.fault_injector is not None:
+                    self.fault_injector.maybe_fail(self._n_batches, "pre")
+                L, it, done, visited = self._delta_solve(src_p, dst_p,
+                                                         pad_k, k)
+                if self._store_edges:
+                    with jax.profiler.TraceAnnotation(tracing.INGEST_STORE):
+                        self._ensure_capacity(self._m + pad_k)
+                        offset = jnp.int32(self._m)
+                        self._src = _ring_write(self._src, src_p, offset)
+                        self._dst = _ring_write(self._dst, dst_p, offset)
+                if self.fault_injector is not None:
+                    self.fault_injector.maybe_fail(self._n_batches,
+                                                   "post_write")
+            except Exception:
+                self._n = old_n
+                self._snap = None
+                raise
+            # commit: the ring store already holds the batch (padding slots
+            # hold self-loops; the next batch's write cursor starts at the
+            # real size and overwrites them) — publish the size, labels and
+            # counters in one uninterruptible run of scalar rebinds
+            with jax.profiler.TraceAnnotation(tracing.INGEST_COMMIT):
+                self._m += k
+                self._labels = L
+                self._iterations = (self._iterations
+                                    + jnp.asarray(it, jnp.int32))
+                self._converged = self._converged & jnp.asarray(done, bool)
+                self._edges_visited = (self._edges_visited
+                                       + jnp.asarray(visited, jnp.float32))
+                self._n_batches += 1
+                self._snap = None
             return self
-        if validate:
-            self._validate_batch(np.asarray(src), np.asarray(dst))
-
-        pad_k = next_pow2(k)
-        src_p, dst_p = _pad_batch(jnp.asarray(src, jnp.int32),
-                                  jnp.asarray(dst, jnp.int32), pad_k)
-
-        # delta re-convergence: sweep only the new batch, warm-started.
-        # Everything up to the scalar commit below runs inside the
-        # rollback guard — vertex growth rolls back on failure (surplus
-        # label capacity is invisible identity padding) and ring writes
-        # only ever touch slots >= _m, which no reader observes — so a
-        # failure anywhere (backend compile error, OOM at a new bucket
-        # size, an injected crash after the ring write) leaves the engine
-        # exactly as it was: ingest is atomic.
-        try:
-            if self.fault_injector is not None:
-                self.fault_injector.maybe_fail(self._n_batches, "pre")
-            L, it, done, visited = self._delta_solve(src_p, dst_p, pad_k, k)
-            if self._store_edges:
-                self._ensure_capacity(self._m + pad_k)
-                offset = jnp.int32(self._m)
-                self._src = _ring_write(self._src, src_p, offset)
-                self._dst = _ring_write(self._dst, dst_p, offset)
-            if self.fault_injector is not None:
-                self.fault_injector.maybe_fail(self._n_batches, "post_write")
-        except Exception:
-            self._n = old_n
-            self._snap = None
-            raise
-        # commit: the ring store already holds the batch (padding slots
-        # hold self-loops; the next batch's write cursor starts at the
-        # real size and overwrites them) — publish the size, labels and
-        # counters in one uninterruptible run of scalar rebinds
-        self._m += k
-        self._labels = L
-        self._iterations = self._iterations + jnp.asarray(it, jnp.int32)
-        self._converged = self._converged & jnp.asarray(done, bool)
-        self._edges_visited = (self._edges_visited
-                               + jnp.asarray(visited, jnp.float32))
-        self._n_batches += 1
-        self._snap = None
-        return self
 
     def _delta_solve(self, src_p, dst_p, pad_k: int, k: int):
         """Run the per-batch delta solve, falling back to XLA on a failed
@@ -474,39 +487,39 @@ class StreamingConnectivity:
             self._snap = None
 
     def _delta_solve_backend(self, src_p, dst_p, pad_k: int, k: int, opts):
-        if opts.mesh is not None:
-            # supervertex rewrite (the single-device path does this
-            # inside delta_converge); self-loop padding maps to
-            # self-loops.  The replica spans the label *capacity* so
-            # its shape matches the resident labels.
+        with jax.profiler.TraceAnnotation(tracing.INGEST_PLAN):
             backend, plan = resolve_backend_plan(self._n_cap, pad_k, opts)
             self._record_plan(plan)
-            return dist.distributed_contour(
-                Graph(src=self._labels[src_p], dst=self._labels[dst_p],
-                      n_vertices=self._n_cap),
-                opts.mesh,
-                edge_axes=tuple(opts.edge_axes),
-                local_rounds=opts.local_rounds,
-                max_iters=opts.max_iters,
-                async_compress=opts.async_compress,
+        with jax.profiler.TraceAnnotation(tracing.INGEST_DELTA_SOLVE):
+            if opts.mesh is not None:
+                # supervertex rewrite (the single-device path does this
+                # inside delta_converge); self-loop padding maps to
+                # self-loops.  The replica spans the label *capacity* so
+                # its shape matches the resident labels.
+                return dist.distributed_contour(
+                    Graph(src=self._labels[src_p], dst=self._labels[dst_p],
+                          n_vertices=self._n_cap),
+                    opts.mesh,
+                    edge_axes=tuple(opts.edge_axes),
+                    local_rounds=opts.local_rounds,
+                    max_iters=opts.max_iters,
+                    async_compress=opts.async_compress,
+                    backend=backend,
+                    plan=plan,
+                    init_labels=self._labels,
+                    sampling=opts.sampling,
+                    compact_every=opts.compact_every,
+                    n_active=k)
+            return delta_converge(
+                src_p, dst_p, self._labels, jnp.int32(k),
+                variant=opts.variant,
                 backend=backend,
                 plan=plan,
-                init_labels=self._labels,
+                warmup=opts.warmup,
+                async_compress=opts.async_compress,
                 sampling=opts.sampling,
                 compact_every=opts.compact_every,
-                n_active=k)
-        backend, plan = resolve_backend_plan(self._n_cap, pad_k, opts)
-        self._record_plan(plan)
-        return delta_converge(
-            src_p, dst_p, self._labels, jnp.int32(k),
-            variant=opts.variant,
-            backend=backend,
-            plan=plan,
-            warmup=opts.warmup,
-            async_compress=opts.async_compress,
-            sampling=opts.sampling,
-            compact_every=opts.compact_every,
-            max_iters=opts.max_iters)
+                max_iters=opts.max_iters)
 
     def ingest_graph(self, graph: Graph,
                      validate: bool = True) -> "StreamingConnectivity":

@@ -49,6 +49,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import tracing
+
 # Padding slots carry this value; min() makes them no-ops.
 _SENTINEL = jnp.iinfo(jnp.int32).max
 
@@ -209,43 +211,47 @@ def binned_scatter_min_pallas(
                           n_blocks, rows, _LANES)
 
     # -- Phase 1: radix-bin the update stream by target // B ---------------
-    blk = targets // B
-    if valid is not None:
-        # dead updates: banished to the tail bin AND value-neutralised, so
-        # the grid-step skip is an optimisation, not a correctness gate
-        blk = jnp.where(valid, blk, n_blocks)
-        values = jnp.where(valid, values, _SENTINEL)
-    order = jnp.argsort(blk, stable=True)
-    t_sorted = targets[order]
-    v_sorted = values[order]
-    blk_sorted = blk[order]
+    with jax.named_scope(tracing.BINNING):
+        blk = targets // B
+        if valid is not None:
+            # dead updates: banished to the tail bin AND value-neutralised,
+            # so the grid-step skip is an optimisation, not a correctness
+            # gate
+            blk = jnp.where(valid, blk, n_blocks)
+            values = jnp.where(valid, values, _SENTINEL)
+        order = jnp.argsort(blk, stable=True)
+        t_sorted = targets[order]
+        v_sorted = values[order]
+        blk_sorted = blk[order]
 
-    counts = jnp.bincount(blk, length=n_bins)
-    padded_counts = _round_up(counts, E)
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), counts.dtype), jnp.cumsum(padded_counts)[:-1]])
-    seg_start = jnp.concatenate(
-        [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]])
-    # position in the boundary-aligned padded layout
-    pos = offsets[blk_sorted] + (jnp.arange(K) - seg_start[blk_sorted])
+        counts = jnp.bincount(blk, length=n_bins)
+        padded_counts = _round_up(counts, E)
+        offsets = jnp.concatenate(
+            [jnp.zeros((1,), counts.dtype), jnp.cumsum(padded_counts)[:-1]])
+        seg_start = jnp.concatenate(
+            [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]])
+        # position in the boundary-aligned padded layout
+        pos = offsets[blk_sorted] + (jnp.arange(K) - seg_start[blk_sorted])
 
-    T = _round_up(K, E) + n_bins * E  # static capacity >= sum(padded)
-    t_pad = jnp.zeros((T,), targets.dtype).at[pos].set(t_sorted)
-    v_pad = jnp.full((T,), _SENTINEL, values.dtype).at[pos].set(v_sorted)
+        T = _round_up(K, E) + n_bins * E  # static capacity >= sum(padded)
+        t_pad = jnp.zeros((T,), targets.dtype).at[pos].set(t_sorted)
+        v_pad = jnp.full((T,), _SENTINEL, values.dtype).at[pos].set(v_sorted)
 
-    n_chunks = T // E
-    chunk_block = jnp.clip(
-        jnp.searchsorted(offsets, jnp.arange(n_chunks) * E, side="right") - 1,
-        0, n_blocks - 1).astype(jnp.int32)
-    # Chunks holding live updates end where the dead bin begins; without a
-    # mask every chunk is live.  (Dead entries were value-masked to
-    # _SENTINEL above, so even a combine that did run would be a no-op —
-    # the skip saves compute, it is not load-bearing for correctness.)
-    if valid is None:
-        live_chunks = jnp.full((1,), n_chunks, jnp.int32)
-    else:
-        dead_start = jnp.cumsum(padded_counts)[n_blocks - 1]
-        live_chunks = (dead_start // E).astype(jnp.int32).reshape((1,))
+        n_chunks = T // E
+        chunk_block = jnp.clip(
+            jnp.searchsorted(offsets, jnp.arange(n_chunks) * E,
+                             side="right") - 1,
+            0, n_blocks - 1).astype(jnp.int32)
+        # Chunks holding live updates end where the dead bin begins;
+        # without a mask every chunk is live.  (Dead entries were
+        # value-masked to _SENTINEL above, so even a combine that did run
+        # would be a no-op — the skip saves compute, it is not
+        # load-bearing for correctness.)
+        if valid is None:
+            live_chunks = jnp.full((1,), n_chunks, jnp.int32)
+        else:
+            dead_start = jnp.cumsum(padded_counts)[n_blocks - 1]
+            live_chunks = (dead_start // E).astype(jnp.int32).reshape((1,))
 
     # -- Phase 2: one pallas_call over chunks, L tiled by BlockSpec --------
     smem = pltpu.MemorySpace.SMEM
@@ -260,13 +266,16 @@ def binned_scatter_min_pallas(
         ],
         out_specs=tile,
     )
-    out = pl.pallas_call(
-        _scatter_min_kernel(B, E),
-        grid_spec=grid_spec,
-        out_shape=_out_struct(L_tiles.shape, L.dtype, t_pad, v_pad, L_tiles),
-        input_output_aliases={4: 0},  # L tile accumulates across chunks
-        interpret=interpret,
-    )(chunk_block, live_chunks, t_pad, v_pad, L_tiles)
+    with jax.named_scope(tracing.SCATTER_KERNEL):
+        out = pl.pallas_call(
+            _scatter_min_kernel(B, E),
+            grid_spec=grid_spec,
+            out_shape=_out_struct(L_tiles.shape, L.dtype, t_pad, v_pad,
+                                  L_tiles),
+            input_output_aliases={4: 0},  # L tile accumulates across chunks
+            interpret=interpret,
+            name=tracing.SCATTER_KERNEL_NAME,
+        )(chunk_block, live_chunks, t_pad, v_pad, L_tiles)
     return out.reshape(n_blocks, rows * _LANES)[:, :B].reshape(-1)[:n]
 
 
@@ -348,41 +357,42 @@ def fused_relax_pallas(
         raise ValueError(
             f"chunk_edges={E} must be a multiple of {HBM_1D_TILE} on TPU "
             "(XLA's tiling of 1-D int32 arrays)")
-    n_pad = _round_up(max(n, 1), HBM_1D_TILE)
-    L_pad = jnp.pad(L, (0, n_pad - n), constant_values=_SENTINEL)
+    with jax.named_scope(tracing.FUSED_RELAX):
+        n_pad = _round_up(max(n, 1), HBM_1D_TILE)
+        L_pad = jnp.pad(L, (0, n_pad - n), constant_values=_SENTINEL)
 
-    if edge_limit is not None:
-        mask = jnp.arange(m, dtype=jnp.int32) < edge_limit
-        src = jnp.where(mask, src, 0)
-        dst = jnp.where(mask, dst, 0)
-    T = max(E, _round_up(m, E))
-    # (0, 0) self-loop padding: relabels to L[0] and scatters z = L²[0]
-    # onto vertex 0 — a no-op under the L[v] <= v labelling invariant
-    src_p = jnp.zeros((T,), src.dtype).at[:m].set(src)
-    dst_p = jnp.zeros((T,), dst.dtype).at[:m].set(dst)
-    n_chunks = T // E
-    if edge_limit is None:
-        live = jnp.full((1,), n_chunks, jnp.int32)
-    else:
-        lim = jnp.minimum(jnp.asarray(edge_limit, jnp.int32), m)
-        live = ((lim + E - 1) // E).reshape((1,))
+        if edge_limit is not None:
+            mask = jnp.arange(m, dtype=jnp.int32) < edge_limit
+            src = jnp.where(mask, src, 0)
+            dst = jnp.where(mask, dst, 0)
+        T = max(E, _round_up(m, E))
+        # (0, 0) self-loop padding: relabels to L[0] and scatters z = L²[0]
+        # onto vertex 0 — a no-op under the L[v] <= v labelling invariant
+        src_p = jnp.zeros((T,), src.dtype).at[:m].set(src)
+        dst_p = jnp.zeros((T,), dst.dtype).at[:m].set(dst)
+        n_chunks = T // E
+        if edge_limit is None:
+            live = jnp.full((1,), n_chunks, jnp.int32)
+        else:
+            lim = jnp.minimum(jnp.asarray(edge_limit, jnp.int32), m)
+            live = ((lim + E - 1) // E).reshape((1,))
 
-    smem = pltpu.MemorySpace.SMEM
-    whole = pl.BlockSpec((n_pad,), lambda c, lv: (0,), memory_space=smem)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_chunks,),
-        in_specs=[
-            pl.BlockSpec((E,), lambda c, lv: (c,), memory_space=smem),
-            pl.BlockSpec((E,), lambda c, lv: (c,), memory_space=smem),
-            whole,
-        ],
-        out_specs=whole,
-    )
-    out = pl.pallas_call(
-        _fused_relax_kernel(n_pad, E),
-        grid_spec=grid_spec,
-        out_shape=_out_struct((n_pad,), L.dtype, src_p, dst_p, L_pad),
-        interpret=interpret,
-    )(live, src_p, dst_p, L_pad)
-    return out[:n]
+        smem = pltpu.MemorySpace.SMEM
+        whole = pl.BlockSpec((n_pad,), lambda c, lv: (0,), memory_space=smem)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_chunks,),
+            in_specs=[
+                pl.BlockSpec((E,), lambda c, lv: (c,), memory_space=smem),
+                pl.BlockSpec((E,), lambda c, lv: (c,), memory_space=smem),
+                whole,
+            ],
+            out_specs=whole,
+        )
+        out = pl.pallas_call(
+            _fused_relax_kernel(n_pad, E),
+            grid_spec=grid_spec,
+            out_shape=_out_struct((n_pad,), L.dtype, src_p, dst_p, L_pad),
+            interpret=interpret,
+        )(live, src_p, dst_p, L_pad)
+        return out[:n]

@@ -41,6 +41,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.connectivity import frontier as fr
 from repro.connectivity import minmap as lab
 from repro.connectivity.planner import vmem as _vmem
@@ -175,58 +176,62 @@ def mm_relax_backend(
     interpret = plan.interpret if interpret is None else interpret
     fuse = plan.fuse_relabel if fuse is None else fuse
 
-    edge_mask = None
-    if edge_limit is not None:
-        edge_mask = jnp.arange(m, dtype=jnp.int32) < edge_limit
+    with jax.named_scope(tracing.MM_SWEEP):
+        edge_mask = None
+        if edge_limit is not None:
+            edge_mask = jnp.arange(m, dtype=jnp.int32) < edge_limit
 
-    if backend == "xla":
-        if edge_mask is not None:
-            # self-loops at vertex 0 are min-mapping no-ops (structs.Graph
-            # padding uses the same trick)
-            src = jnp.where(edge_mask, src, 0)
-            dst = jnp.where(edge_mask, dst, 0)
-        return lab.mm_relax(L, src, dst, order)
-    if backend == "pallas":
-        if not interpret:
-            raise ValueError(
-                "the scalar 'pallas' kernel does not compile for TPU "
-                "(Mosaic cannot store scalars to VMEM); use "
-                "'pallas_blocked' or 'xla'")
-        if order != 2:
-            raise ValueError(
-                "the scalar 'pallas' kernel is 2-order only; use "
-                "'pallas_blocked' or 'xla' for order != 2")
-        ceiling = _vmem.whole_l_vmem_ceiling(platform,
-                                             vmem_bytes=vmem_limit_bytes)
-        if n > ceiling:
-            raise ValueError(
-                f"n_vertices={n} exceeds the scalar 'pallas' kernel's "
-                f"whole-L VMEM ceiling ({ceiling}); use 'pallas_blocked' "
-                "(label-tiled, no ceiling) or 'xla', or raise the budget "
-                f"via SolveOptions.vmem_limit_bytes / ${_vmem.ENV_VMEM_BYTES}")
-        if edge_mask is not None:
-            src = jnp.where(edge_mask, src, 0)
-            dst = jnp.where(edge_mask, dst, 0)
-        src_p, dst_p = _pad_edges(src, dst, block_edges)
-        return mm2_pallas(src_p, dst_p, L, block_edges=block_edges,
-                          interpret=interpret)
-    # pallas_blocked
-    if fuse and order == 2 and max(128, _round_up(n, 128)) <= label_block:
-        # single-tile regime: one Pallas pass does gathers (relabel) and
-        # all four scatter-min combines — no update-stream materialisation,
-        # no radix binning, no argsort
-        return fused_relax_pallas(
-            L, src, dst, chunk_edges=chunk_updates, interpret=interpret,
-            edge_limit=edge_limit)
-    t, v = lab.mm_update_stream(L, src, dst, order)
-    valid = None
-    if edge_mask is not None:
-        # the stream is 2*order concatenated [m] segments (targets per
-        # Definition 3); each inherits the per-edge liveness
-        valid = jnp.tile(edge_mask, 2 * order)
-    return binned_scatter_min_pallas(
-        L, t, v, label_block=label_block, chunk_updates=chunk_updates,
-        interpret=interpret, valid=valid)
+        if backend == "xla":
+            with jax.named_scope(tracing.SCATTER_MIN):
+                if edge_mask is not None:
+                    # self-loops at vertex 0 are min-mapping no-ops
+                    # (structs.Graph padding uses the same trick)
+                    src = jnp.where(edge_mask, src, 0)
+                    dst = jnp.where(edge_mask, dst, 0)
+                return lab.mm_relax(L, src, dst, order)
+        if backend == "pallas":
+            if not interpret:
+                raise ValueError(
+                    "the scalar 'pallas' kernel does not compile for TPU "
+                    "(Mosaic cannot store scalars to VMEM); use "
+                    "'pallas_blocked' or 'xla'")
+            if order != 2:
+                raise ValueError(
+                    "the scalar 'pallas' kernel is 2-order only; use "
+                    "'pallas_blocked' or 'xla' for order != 2")
+            ceiling = _vmem.whole_l_vmem_ceiling(platform,
+                                                 vmem_bytes=vmem_limit_bytes)
+            if n > ceiling:
+                raise ValueError(
+                    f"n_vertices={n} exceeds the scalar 'pallas' kernel's "
+                    f"whole-L VMEM ceiling ({ceiling}); use 'pallas_blocked' "
+                    "(label-tiled, no ceiling) or 'xla', or raise the budget "
+                    "via SolveOptions.vmem_limit_bytes / "
+                    f"${_vmem.ENV_VMEM_BYTES}")
+            if edge_mask is not None:
+                src = jnp.where(edge_mask, src, 0)
+                dst = jnp.where(edge_mask, dst, 0)
+            src_p, dst_p = _pad_edges(src, dst, block_edges)
+            return mm2_pallas(src_p, dst_p, L, block_edges=block_edges,
+                              interpret=interpret)
+        # pallas_blocked
+        if fuse and order == 2 and max(128, _round_up(n, 128)) <= label_block:
+            # single-tile regime: one Pallas pass does gathers (relabel)
+            # and all four scatter-min combines — no update-stream
+            # materialisation, no radix binning, no argsort
+            return fused_relax_pallas(
+                L, src, dst, chunk_edges=chunk_updates, interpret=interpret,
+                edge_limit=edge_limit)
+        with jax.named_scope(tracing.UPDATE_STREAM):
+            t, v = lab.mm_update_stream(L, src, dst, order)
+            valid = None
+            if edge_mask is not None:
+                # the stream is 2*order concatenated [m] segments (targets
+                # per Definition 3); each inherits the per-edge liveness
+                valid = jnp.tile(edge_mask, 2 * order)
+        return binned_scatter_min_pallas(
+            L, t, v, label_block=label_block, chunk_updates=chunk_updates,
+            interpret=interpret, valid=valid)
 
 
 @functools.partial(
