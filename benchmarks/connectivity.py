@@ -7,8 +7,8 @@ algorithm ConnectIt found fastest on shared memory), host-side per
 DESIGN.md §8.5, with iteration count 1 by the paper's convention (§IV-C).
 
 ``C-2-blk`` is the kernel-subsystem path (DESIGN.md §3.4): the dispatched
-contour_mm backend (label-blocked Pallas on TPU, scatter-min under XLA on
-CPU hosts) iterated by the on-device ``lax.while_loop`` fixpoint of
+contour_mm backend (the sweep the planner picks for the platform and
+graph size) iterated by the on-device ``lax.while_loop`` fixpoint of
 ``contour_cc_fixpoint`` — zero per-iteration host syncs.  ``C-2-cmp`` is
 C-2 under the work-adaptive frontier contraction schedule (DESIGN.md §10:
 sampling prefix, largest-component filter, periodic active-edge

@@ -12,7 +12,9 @@ One chip (the default):
      included) and warm.  Labels must equal scipy's connected components
      relabelled to the minimum vertex id, and the provenance must show no
      fallback.
-  B. The same graph on ``backend="xla"``; labels must equal A's.
+  B. The same graph on the label-blocked Pallas kernel, forced
+     (``backend="pallas_blocked"``; the planner picks XLA at this size);
+     labels must equal A's.
   C. A single-tile graph (scale 12, n = 4096), where the planner picks the
      fused relabel + scatter-min Pallas pass.
   D. ``StreamingConnectivity`` ingests A's edges in 8 batches; its
@@ -155,11 +157,11 @@ def one_chip(seed: int, device) -> None:
          peak_bytes_in_use=peak_bytes(device),
          device_kind=device.device_kind)
 
-    # -- B: the XLA reference backend on the same graph ---------------------
+    # -- B: the blocked Pallas kernel, forced, on the same graph ------------
     log("B: start")
-    res_x, cold = timed(lambda: solve(g, opts, backend="xla"))
-    res_x, warm = timed(lambda: solve(g, opts, backend="xla"))
-    check_equal("B xla", res_x.labels, labels_a)
+    res_x, cold = timed(lambda: solve(g, opts, backend="pallas_blocked"))
+    res_x, warm = timed(lambda: solve(g, opts, backend="pallas_blocked"))
+    check_equal("B pallas_blocked", res_x.labels, labels_a)
     emit(phase="B", plan=plan_of(res_x), compile_plus_run_s=cold,
          warm_s=warm, iterations=int(res_x.iterations),
          peak_bytes_in_use=peak_bytes(device))

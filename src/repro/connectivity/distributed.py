@@ -10,10 +10,10 @@ Mapping of the paper's Arkouda/Chapel distribution onto a TPU mesh
   2³⁰-vertex graph is a 4 GB replica, fine for 16 GB HBM chips; an
   all-to-all label-sharded variant is the documented scale-out path);
 * each global round: every device relaxes its local edge shard (through
-  the ``kernels.contour_mm`` backend dispatch — XLA scatter-min on CPU
-  hosts, the label-blocked Pallas kernel on TPU) and compresses, then one
-  ``lax.pmin`` all-reduce merges label arrays — the collective is the
-  *only* cross-device traffic;
+  the ``kernels.contour_mm`` backend dispatch, which realises the sweep
+  the planner picks) and compresses, then one ``lax.pmin`` all-reduce
+  merges label arrays — the collective is the *only* cross-device
+  traffic;
 * convergence: the paper's early-convergence predicate evaluated on local
   edges, AND-reduced across devices.
 
@@ -232,7 +232,8 @@ def distributed_contour(
     meshes in ``repro.launch.mesh`` and the multi-device CPU test mesh
     alike.  ``backend`` selects the per-shard sweep realisation through
     the shared ``kernels.contour_mm`` dispatch layer ("xla" scatter-min by
-    default; "pallas_blocked"/"auto" for the label-blocked TPU kernel).
+    default; "pallas_blocked" for the label-blocked TPU kernel; "auto" for
+    the planner's choice).
 
     ``n_active`` overrides the real-edge count when the caller's graph is
     itself already padded with trailing self-loops (the streaming engine's

@@ -54,6 +54,7 @@ from repro.connectivity.planner.heuristics import (
     OOCORE_BYTES_PER_EDGE,
     SINGLE_TILE_MAX_N,
     STAGED_MIN_EDGES,
+    XLA_SWEEP_MIN_EDGES,
     heuristic_plan,
     oocore_chunk_bucket,
 )
@@ -83,6 +84,7 @@ __all__ = [
     "ORIGINS",
     "SINGLE_TILE_MAX_N",
     "STAGED_MIN_EDGES",
+    "XLA_SWEEP_MIN_EDGES",
     "ExecutionPlan",
     "autotune",
     "cache",

@@ -59,13 +59,13 @@ def candidate_plans(n_vertices: int, m_edges: int,
     for schedule in ("masked", "staged"):
         add(base.replace(compact_schedule=schedule))
     if platform == "tpu":
-        # tile-size neighbourhood of the prior, in shapes Mosaic compiles
-        # (the combine cost per update is ∝ label_block; bin padding
-        # waste is ∝ blocks·chunk)
+        # the blocked kernel at a tile-size neighbourhood, in shapes
+        # Mosaic compiles (the combine cost per update is ∝ label_block;
+        # bin padding waste is ∝ blocks·chunk)
         for lb in (1024, 2048, 4096):
             for cu in (1024, 4096):
-                add(base.replace(label_block=lb, chunk_updates=cu,
-                                 fuse_relabel=False))
+                add(base.replace(backend="pallas_blocked", label_block=lb,
+                                 chunk_updates=cu, fuse_relabel=False))
         if base.fuse_relabel:
             add(base.replace(fuse_relabel=False))
     return cands

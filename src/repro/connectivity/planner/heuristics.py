@@ -27,6 +27,10 @@ STAGED_MIN_EDGES = 1 << 15
 # materialisation, no radix binning).
 SINGLE_TILE_MAX_N = 4096
 
+# Fewest edges at which the TPU table sweeps on XLA's scatter-min above
+# the single-tile regime (measured on a v5e, PERF.md section 6).
+XLA_SWEEP_MIN_EDGES = 1 << 20
+
 # TPU tile unit of the blocked and fused kernels: one (8, 128) int32 vreg
 # of labels, and XLA's tiling of 1-D int32 arrays in HBM (update chunks).
 TPU_TILE = 1024
@@ -54,17 +58,25 @@ def heuristic_plan(
     """Pick backend + tile sizes + schedule for a graph size, by table.
 
     Off-TPU the only compilable backend is XLA scatter-min.  On TPU the
-    blocked kernel is always eligible (no ceiling); its tiles are the
-    smallest shapes Mosaic compiles densely:
+    table follows what the chip measured (``experiments/sweep_crossover.py``,
+    PERF.md section 6):
 
     * small graphs (n <= :data:`SINGLE_TILE_MAX_N`) hold all of L in one
-      tile, where the fused relabel+scatter-min pass skips the
+      tile, where the fused relabel+scatter-min Pallas pass skips the
       update-stream materialisation entirely (L in SMEM);
-    * large graphs use 1024-slot label tiles — one (8, 128) int32 vreg,
-      so each update costs one vector compare/select/min — grown only
-      when the bin count would overflow the kernel's SMEM chunk map.
-      Chunks are 1024 updates (XLA's 1-D int32 HBM tiling); the kernel
-      doubles them if the stream's chunk map would overflow SMEM.
+    * larger graphs with at least :data:`XLA_SWEEP_MIN_EDGES` edges take
+      XLA's scatter-min: whole solves ran 3.6x (Delaunay, 3.1M edges) to
+      4.5x (Graph500, 2^24 edges) faster on it, since the radix binning
+      the blocked kernel needs costs more than XLA's whole sweep;
+    * larger graphs with fewer edges, such as a stream's 2^16-edge delta
+      solves, keep the label-blocked kernel: there a batch ran about 4%
+      slower on XLA's sweep.
+
+    The blocked kernel's tiles: 1024-slot label tiles — one (8, 128) int32
+    vreg, so each update costs one vector compare/select/min — grown only
+    when the bin count would overflow the kernel's SMEM chunk map, and
+    1024-update chunks (XLA's 1-D int32 HBM tiling), which the kernel
+    doubles if the stream's chunk map would overflow SMEM.
 
     ``compact_schedule`` only matters when the caller also enables the
     work-adaptive frontier (``sampling``/``compact_every``): big edge
@@ -88,7 +100,8 @@ def heuristic_plan(
         fuse = False
     block_edges = 512 if n_edges < 1 << 20 else 2048
     return ExecutionPlan(
-        backend="pallas_blocked",
+        backend=("xla" if not fuse and n_edges >= XLA_SWEEP_MIN_EDGES
+                 else "pallas_blocked"),
         block_edges=block_edges,
         label_block=label_block,
         chunk_updates=TPU_TILE,

@@ -17,9 +17,13 @@ Three device backends realise the same MM^h sweep (DESIGN.md §3):
   vertex ceiling, VPU-vectorized scatter-min, any order.  Per sweep it is
   bit-exact equal to ``"xla"``.
 
-``"auto"`` picks per graph size and platform via :func:`plan_contour_kernel`
-— the shared dispatch/autotune layer used by `core.contour`,
-`core.distributed` and `benchmarks.connectivity`.
+``"auto"`` picks per graph size and platform from the planner's tables
+(``planner.heuristic_plan``): ``"xla"`` off-TPU; on TPU the fused
+single-tile ``"pallas_blocked"`` pass for n <= 4096, and above it
+``"xla"`` from ``XLA_SWEEP_MIN_EDGES`` edges on, where the blocked
+kernel's radix binning outweighs XLA's whole scatter-min sweep, and the
+blocked kernel below that.  Forced, ``"pallas_blocked"`` runs at any
+size.
 
 :func:`contour_cc_fixpoint` iterates any backend to the connectivity fixed
 point inside a single ``lax.while_loop`` — the convergence flag stays on

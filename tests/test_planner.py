@@ -31,6 +31,8 @@ from repro.connectivity import SolveOptions, solve, solve_batch
 from repro.connectivity import planner
 from repro.connectivity.contour import contour_labels
 from repro.connectivity.planner import (
+    SINGLE_TILE_MAX_N,
+    XLA_SWEEP_MIN_EDGES,
     ExecutionPlan,
     cache,
     heuristic_plan,
@@ -63,9 +65,35 @@ def test_heuristic_plan_is_platform_and_size_aware():
     assert small.backend == "pallas_blocked"
     assert small.fuse_relabel and small.label_block >= 1000
     big = heuristic_plan(1 << 20, 1 << 22, "tpu")
+    assert big.backend == "xla"
     assert not big.fuse_relabel and big.label_block == 1024
+    edge = heuristic_plan(SINGLE_TILE_MAX_N, 1 << 22, "tpu")
+    assert edge.backend == "pallas_blocked" and edge.fuse_relabel
+    # above one tile the edge count picks the sweep
+    assert heuristic_plan(SINGLE_TILE_MAX_N + 1, XLA_SWEEP_MIN_EDGES,
+                          "tpu").backend == "xla"
+    few = heuristic_plan(SINGLE_TILE_MAX_N + 1, XLA_SWEEP_MIN_EDGES - 1,
+                         "tpu")
+    assert few.backend == "pallas_blocked" and not few.fuse_relabel
     assert heuristic_plan(100, 100, "tpu").compact_schedule == "masked"
     assert heuristic_plan(100, 1 << 16, "tpu").compact_schedule == "staged"
+
+
+@pytest.mark.parametrize("n_vertices,n_edges,backend", [
+    (1 << 20, 1 << 24, "xla"),             # graph500-s20.solve
+    (1 << 20, 3_145_688, "xla"),           # delaunay-n20.solve
+    (1 << 20, 1 << 16, "pallas_blocked"),  # stream-b16: one padded batch
+    (1 << 22, 64_151_199, "xla"),          # graph500-22
+], ids=["graph500-s20", "delaunay-n20", "stream-b16", "graph500-22"])
+def test_tpu_table_backend_at_the_cell_shapes(n_vertices, n_edges, backend):
+    """The sweep the chip measured faster at each of these shapes (PERF.md
+    section 6); a forced blocked kernel keeps its tiles."""
+    plan = heuristic_plan(n_vertices, n_edges, "tpu")
+    assert plan.backend == backend and not plan.fuse_relabel
+    forced = resolve_plan(n_vertices, n_edges, backend="pallas_blocked",
+                          platform="tpu")
+    assert forced.backend == "pallas_blocked" and not forced.interpret
+    assert (forced.label_block, forced.chunk_updates) == (1024, 1024)
 
 
 def test_pinned_plan_wins_over_cache(tmp_cache, monkeypatch):

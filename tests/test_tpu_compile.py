@@ -20,8 +20,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro import jax_compat
+from repro.connectivity.contour import contour_labels
 from repro.connectivity.distributed import _distributed_fixpoint
-from repro.connectivity.planner import heuristic_plan
+from repro.connectivity.planner import heuristic_plan, resolve_plan
 from repro.kernels.contour_mm.blocked import (binned_scatter_min_pallas,
                                               fused_relax_pallas)
 
@@ -29,6 +30,9 @@ from repro.kernels.contour_mm.blocked import (binned_scatter_min_pallas,
 # generators.rmat(22, edge_factor=16, seed=0), the chip smoke's graph.
 G500_22_N = 1 << 22
 G500_22_M = 64_151_199
+# The benchmark's graph500-s20 cell: Graph500 scale 20, edge factor 16.
+G500_20_N = 1 << 20
+G500_20_M = 1 << 24
 
 
 @pytest.fixture(scope="module")
@@ -71,9 +75,10 @@ def _assert_kernel(compiled):
                          ids=["graph500-22", "n2^27"])
 def test_binned_scatter_min_compiles_at_graph500_22_stream(one_chip,
                                                            n_vertices):
-    """The blocked sweep at the tiles the TPU table picks, on the order-2
-    update stream of graph500-22 (4m updates)."""
-    plan = heuristic_plan(n_vertices, G500_22_M, "tpu")
+    """The blocked sweep at the tiles a forced ``pallas_blocked`` plan
+    takes, on the order-2 update stream of graph500-22 (4m updates)."""
+    plan = resolve_plan(n_vertices, G500_22_M, backend="pallas_blocked",
+                        platform="tpu")
     assert plan.backend == "pallas_blocked" and not plan.fuse_relabel
     k = 4 * G500_22_M
 
@@ -87,6 +92,21 @@ def test_binned_scatter_min_compiles_at_graph500_22_stream(one_chip,
         _i32((n_vertices,), one_chip), _i32((k,), one_chip),
         _i32((k,), one_chip)).compile()
     _assert_kernel(compiled)
+
+
+def test_default_solve_compiles_on_xla_sweep_at_graph500_s20(one_chip):
+    """The whole default solve program at the graph500-s20 cell's shape:
+    the table's XLA sweep, with no Mosaic kernel in it, fitting one chip."""
+    plan = heuristic_plan(G500_20_N, G500_20_M, "tpu")
+    assert plan.backend == "xla"
+    compiled = contour_labels.lower(
+        _i32((G500_20_M,), one_chip), _i32((G500_20_M,), one_chip),
+        G500_20_N, backend=plan.backend, plan=plan).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < 16 * 2**30
 
 
 def test_fused_relax_compiles_at_single_tile_ceiling(one_chip):
@@ -116,7 +136,8 @@ def test_sharded_fixpoint_compiles_with_blocked_kernel(topo):
         _i32((m,), edges), _i32((m,), edges), _i32((n,), rep),
         _i32((), rep), mesh=mesh, edge_axes=("data",), local_rounds=1,
         max_iters=100, async_compress=1, backend="pallas_blocked",
-        plan=heuristic_plan(n, m, "tpu"), sampling=0,
+        plan=resolve_plan(n, m, backend="pallas_blocked", platform="tpu"),
+        sampling=0,
         compact_every=0).compile()
     txt = compiled.as_text()
     assert "tpu_custom_call" in txt
