@@ -17,13 +17,20 @@ harness finds by that name.  A loop module offers:
 Loops reach the program only through its public entry points, looked up
 on ``repro`` at call time (``repro.solve``, ``repro.StreamingConnectivity``)
 with default options, so that the plan the planner picks is part of what
-is measured.
+is measured.  A loop of a cell on several chips passes ``mesh=mesh_of(cell)``
+and places its drawn edges on that mesh once, at set-up, with
+``place_edges``, so that its window moves no edge between chips.
 """
 from __future__ import annotations
 
 import sys
 
 import jax
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+# the mesh axis a cell's edge list is split along
+EDGE_AXIS = "data"
 
 span = jax.profiler.TraceAnnotation
 
@@ -65,3 +72,24 @@ def half_the_sweeps(real):
                     **kwargs)
 
     return solve
+
+
+def mesh_of(cell) -> jax.sharding.Mesh:
+    """A one-axis ``("data",)`` mesh of the first ``cell.chips`` devices."""
+    devices = jax.devices()[:cell.chips]
+    if len(devices) < cell.chips:
+        raise RuntimeError(f"{cell.name} needs {cell.chips} devices; JAX "
+                           f"found {len(devices)}")
+    return jax.sharding.Mesh(np.array(devices), (EDGE_AXIS,),
+                             axis_types=(jax.sharding.AxisType.Auto,))
+
+
+def place_edges(mesh: jax.sharding.Mesh, src, dst):
+    """``(src, dst)`` split into equal contiguous blocks over ``mesh``'s
+    edge axis, one per chip, as ``solve(graph, mesh=mesh)`` shards them."""
+    chips = mesh.shape[EDGE_AXIS]
+    if src.shape[0] % chips:
+        raise ValueError(f"{src.shape[0]} edges do not split evenly over "
+                         f"{chips} chips")
+    out = jax.device_put((src, dst), NamedSharding(mesh, P(EDGE_AXIS)))
+    return jax.block_until_ready(out)

@@ -13,8 +13,11 @@ gives it:
 * per-layer metric: ``bench/metrics/<name>.py``, whose ``read(run)``
   returns the number, or None where it finds nothing to read.
 
-A later cell is added with files and one ``workloads`` entry; no file
-here changes.
+A later cell is added with files, one ``workloads`` entry, and its name
+appended to the ``workloads`` lists of the metrics it reports; no file
+here changes.  That holds for a cell of several chips too: its loop
+builds its mesh and places its edges with ``bench/drive.py``, and the
+result's ``device`` reads the memory of every chip the cell uses.
 """
 from __future__ import annotations
 
@@ -166,7 +169,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         jax.monitoring.unregister_event_duration_listener(counter)
     counters["compiles_in_window"] = counter.count
     counters["setup_s"] = setup_s
-    peak = (device.memory_stats() or {}).get("peak_bytes_in_use")
+    # each chip the cell uses; None where the backend keeps no statistic
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.devices()[:cell.chips]]
     t_check = time.perf_counter()
     checks = loop.check()
     del loop
@@ -176,8 +181,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
               f"{counters['window_s']:.3f} s, check "
               f"{time.perf_counter() - t_check:.3f} s")
 
+    known = [p for p in peaks if p is not None]
     dev = {"platform": device.platform, "kind": device.device_kind,
-           "count": len(jax.devices()), "memory_peak_bytes": peak}
+           "count": len(jax.devices()), "chips": cell.chips,
+           "memory_peak_bytes": max(known) if known else None,
+           "memory_peak_bytes_per_chip": peaks}
     metrics = {}
     breakdown = None
     if trace:

@@ -51,12 +51,13 @@ WINDOW_MATCH_NS = 1000.0
 MM_SWEEP = "mm_sweep"
 BINNING = "binning"
 SCATTER_KERNEL = "scatter_kernel"
+SCATTER_MIN = "scatter_min"
 POINTER_JUMP = "pointer_jump"
 CONVERGE_CHECK = "converge_check"
 COMPACT = "compact"
 COMPRESS = "compress"
 DEVICE_SCOPES = (MM_SWEEP, "update_stream", BINNING, SCATTER_KERNEL,
-                 "fused_relax", "scatter_min", POINTER_JUMP, CONVERGE_CHECK,
+                 "fused_relax", SCATTER_MIN, POINTER_JUMP, CONVERGE_CHECK,
                  COMPACT, COMPRESS, "supervertex_rewrite", "sampling_prep",
                  "pad", "store", "label_allreduce")
 INGEST_SPAN = "repro.ingest"

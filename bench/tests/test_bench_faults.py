@@ -3,10 +3,11 @@
 Each cell is driven below the harness's look for a chip, on the CPU at a
 small size, with one fault planted in the program's place: a step that
 returns its state unchanged, half of each batch left out, and an answer
-altered where it is produced.  The cells run on one chip, so there is no
-exchange between chips to leave out.  The control (each loop's
-``control()``, read on the chip by ``bench/control.py``) must fail too,
-and a sound run must pass.
+altered where it is produced.  These cells run on one chip and have no
+exchange between chips to leave out; a cell of four chips is broken so
+in ``test_bench_mesh.py``, on four CPU devices.  The control (each
+loop's ``control()``, read on the chip by ``bench/control.py``) must
+fail too, and a sound run must pass.
 """
 import dataclasses
 

@@ -2,22 +2,27 @@
 import json
 import os
 import re
-import shutil
 
 import pytest
 
 from bench import harness
-from bench.tests.conftest import ROOT, run_tiny
+from bench.tests.conftest import (ROOT, copy_benchmark, run_tiny,
+                                  write_new)
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
-def test_every_cell_of_the_benchmark_resolves():
-    bm = harness.load_benchmark(ROOT)
+def check_cells(root):
+    """Every cell of ``<root>/BENCHMARK.json`` resolves to its files, on 1
+    or 4 chips, with at most half the cells (rounded down, or one where
+    there are fewer) on 4."""
+    bm = harness.load_benchmark(root)
     e2e = {m["name"] for m in bm["end_to_end"]}
+    four = 0
     for w in bm["workloads"]:
-        cell = harness.resolve(ROOT, w["name"])
-        assert cell.chips == 1
+        cell = harness.resolve(root, w["name"])
+        assert cell.chips in (1, 4)
+        four += cell.chips == 4
         names = {m["name"] for m in cell.end_to_end}
         # setup_s and one more end-to-end metric, and one per-layer one
         # that moves a metric this cell reports
@@ -29,6 +34,28 @@ def test_every_cell_of_the_benchmark_resolves():
         assert NAME.match(entry["name"]), entry["name"]
     for m in bm["per_layer"]:
         assert m["moves"] in e2e
+    assert four <= max(1, len(bm["workloads"]) // 2)
+
+
+def test_every_cell_of_the_benchmark_resolves():
+    check_cells(ROOT)
+
+
+def test_more_than_half_the_cells_on_four_chips_is_refused(tmp_path):
+    copy_benchmark(tmp_path)
+    path = tmp_path / "BENCHMARK.json"
+    bm = json.loads(path.read_text())
+    cells = bm["workloads"]
+    allowed = max(1, len(cells) // 2)
+    for four in (allowed, allowed + 1):
+        for i, w in enumerate(cells):
+            w["chips"] = 4 if i < four else 1
+        path.write_text(json.dumps(bm))
+        if four == allowed:
+            check_cells(tmp_path)
+        else:
+            with pytest.raises(AssertionError):
+                check_cells(tmp_path)
 
 
 # a loop of its own: a stream from no edges at all, fed batches from a
@@ -103,12 +130,6 @@ class Batches:
 """
 
 
-def _write(path, text):
-    assert not os.path.exists(path), path
-    with open(path, "w") as f:
-        f.write(text)
-
-
 def _add_cells(root):
     """Two cells added as files, with one entry each in BENCHMARK.json;
     no file that was there is touched.  ``kron-s9.solve-w2``: a
@@ -116,24 +137,24 @@ def _add_cells(root):
     loop.  ``ring-9.cold``: a traffic loop, a graph generator and a
     batch generator of its own besides."""
     bench = os.path.join(root, "bench")
-    _write(os.path.join(bench, "configs", "kron-s9.json"), json.dumps(
+    write_new(os.path.join(bench, "configs", "kron-s9.json"), json.dumps(
         {"name": "kron-s9", "generator": "kronecker", "scale": 9,
          "edge_factor": 8, "initiator": [0.45, 0.15, 0.15]}))
-    _write(os.path.join(bench, "traffic", "solve-w2.json"),
-           json.dumps({"loop": "solve", "about": "test"}))
-    _write(os.path.join(bench, "metrics", "solves.window.py"),
-           "def read(run):\n"
-           "    return float(run.counters['ops'])\n")
-    _write(os.path.join(bench, "configs", "ring-9.json"),
-           json.dumps({"name": "ring-9", "generator": "ring", "n": 512}))
-    _write(os.path.join(bench, "generators", "ring.py"), RING_GENERATOR)
-    _write(os.path.join(bench, "batches", "ring.py"), RING_BATCHES)
-    _write(os.path.join(bench, "loops", "cold-stream.py"), COLD_LOOP)
-    _write(os.path.join(bench, "traffic", "cold.json"),
-           json.dumps({"loop": "cold-stream", "batch_edges": 64}))
-    _write(os.path.join(bench, "metrics", "batches.cold.py"),
-           "def read(run):\n"
-           "    return run.counters.get('batches')\n")
+    write_new(os.path.join(bench, "traffic", "solve-w2.json"),
+              json.dumps({"loop": "solve", "about": "test"}))
+    write_new(os.path.join(bench, "metrics", "solves.window.py"),
+              "def read(run):\n"
+              "    return float(run.counters['ops'])\n")
+    write_new(os.path.join(bench, "configs", "ring-9.json"),
+              json.dumps({"name": "ring-9", "generator": "ring", "n": 512}))
+    write_new(os.path.join(bench, "generators", "ring.py"), RING_GENERATOR)
+    write_new(os.path.join(bench, "batches", "ring.py"), RING_BATCHES)
+    write_new(os.path.join(bench, "loops", "cold-stream.py"), COLD_LOOP)
+    write_new(os.path.join(bench, "traffic", "cold.json"),
+              json.dumps({"loop": "cold-stream", "batch_edges": 64}))
+    write_new(os.path.join(bench, "metrics", "batches.cold.py"),
+              "def read(run):\n"
+              "    return run.counters.get('batches')\n")
     path = os.path.join(root, "BENCHMARK.json")
     with open(path) as f:
         bm = json.load(f)
@@ -165,10 +186,7 @@ def _add_cells(root):
 @pytest.fixture(scope="module")
 def added(tmp_path_factory):
     root = tmp_path_factory.mktemp("checkout")
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(os.path.join(ROOT, "bench"), root / "bench",
-                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
-    before = {p: open(p, "rb").read() for p in _files(root)}
+    before = copy_benchmark(root)
     _add_cells(str(root))
     for p, data in before.items():
         assert open(p, "rb").read() == data
@@ -189,9 +207,3 @@ def test_an_added_cell_is_found_by_name_and_runs(added, name, e2e, metric):
     assert line["correct"] and set(line["metrics"]) == {e2e, "setup_s"}
     line, _ = run_tiny(cell, trace_dir=str(added / ("trace-" + name)))
     assert line["metrics"][metric]["value"] == line["attempted"]
-
-
-def _files(root):
-    for d, _, files in os.walk(os.path.join(root, "bench")):
-        for f in files:
-            yield os.path.join(d, f)

@@ -20,7 +20,7 @@ SCOPED = {"solve": os.path.join(FIXTURES, "scoped_solve_v5e.trace.json.gz"),
                                  "scoped_stream_v5e.trace.json.gz")}
 SCOPED_XPLANE = os.path.join(FIXTURES, "scoped_solve_v5e.xplane.pb")
 
-NEW_METRICS = ("binning_ms.solve", "kernel_ms.solve", "fixpoint_ms.solve",
+NEW_METRICS = ("scatter_min_ms.solve", "fixpoint_ms.solve",
                "label_pass_ms.stream", "ingest_host_ms.stream")
 
 
@@ -77,9 +77,7 @@ def test_every_new_reader_is_null_on_the_unscoped_trace(tmp_path,
 
 # what the harness read from each window on the chip, with the counters
 # of that window (3 sweeps; 11 batches)
-ON_CHIP = {"solve": ({"sweeps": 3}, {"binning_ms.solve": 36.36685981799999,
-                                     "kernel_ms.solve": 1.2832242186666678,
-                                     "fixpoint_ms.solve": 3.8191755726666403}),
+ON_CHIP = {"solve": ({"sweeps": 3}, {"fixpoint_ms.solve": 3.8191755726666403}),
            "stream": ({"batches": 11},
                       {"label_pass_ms.stream": 0.1457215341818089,
                        "ingest_host_ms.stream": 4.569568636363636})}
@@ -99,9 +97,37 @@ def test_every_reader_reads_its_scoped_trace(tmp_path, monkeypatch, kind):
         sweep = t.scope_ns([pt.MM_SWEEP], spans)
         assert t.scope_ns([pt.BINNING], spans) + t.scope_ns(
             [pt.SCATTER_KERNEL], spans) <= sweep * (1 + 1e-9)
+        # this window swept with the blocked kernel, not XLA's scatter-min
+        assert _reader("scatter_min_ms.solve")(run) is None
     else:
         ingests = t.span_ns(pt.INGEST_SPAN)
         assert len(ingests) == 11
+
+
+def test_scatter_min_is_read_per_sweep_and_averaged_over_chips():
+    """Two chips: the leaf ops under ``mm_sweep/scatter_min`` inside the
+    solve spans, per sweep, averaged over the chips; other scopes and
+    time outside those spans do not count."""
+    body = "jit(contour_labels)/while/body/"
+    sweep = body + "mm_sweep/scatter_min/"
+    ops = [[(sweep + "gather:", 10, 30), (sweep + "scatter:", 30, 40),
+            (body + "converge_check/gather:", 40, 50),
+            (sweep + "scatter:", 85, 95)],      # after labels_to_host
+           [(sweep + "scatter:", 15, 35),
+            (body + "pointer_jump/gather:", 35, 45)]]
+    spans = [("window", {}, 0, 100), ("solve", {}, 0, 60),
+             ("labels_to_host", {}, 60, 80)]
+    window = tr.Trace(device_ops=[], spans=[("window", 0, 100)])
+    run = harness.Run(cell=None, counters={"sweeps": 5}, trace=window,
+                      device_kind="TPU v5 lite")
+    run.program_trace = pt.ProgramTrace(device_ops=ops, spans=spans)
+    # chip 0: 20 + 10 ns; chip 1: 20 ns; 5 sweeps
+    assert _reader("scatter_min_ms.solve")(run) == pytest.approx(
+        (30 + 20) / 2 / 1e6 / 5)
+    run.program_trace = pt.ProgramTrace(
+        device_ops=[[op for op in chip if "scatter_min" not in op[0]]
+                    for chip in ops], spans=spans)
+    assert _reader("scatter_min_ms.solve")(run) is None
 
 
 def test_the_two_file_formats_read_alike():
@@ -123,13 +149,13 @@ def test_a_stale_trace_directory_is_refused(tmp_path, monkeypatch):
     shutil.copy(UNSCOPED, stale / "host.xplane.pb")
     later = os.path.getmtime(tmp_path / "bench-trace-run") + 60
     os.utime(tmp_path / "bench-trace-stale", (later, later))
-    assert _reader("binning_ms.solve")(run) is not None
+    assert _reader("fixpoint_ms.solve")(run) is not None
 
     shutil.rmtree(tmp_path / "bench-trace-run")
     run = harness.Run(cell=None, counters={"sweeps": 3}, trace=run.trace,
                       device_kind="TPU v5 lite")
     assert pt.for_run(run) is None
-    assert _reader("binning_ms.solve")(run) is None
+    assert _reader("fixpoint_ms.solve")(run) is None
 
 
 def test_span_names_and_arguments():
