@@ -67,8 +67,18 @@ class _FrontierShardState(NamedTuple):
     visited: jax.Array     # float32, psum-reduced (identical on all shards)
 
 
-def _round_up(x: int, k: int) -> int:
-    return (x + k - 1) // k * k
+def _n_shards(mesh: jax.sharding.Mesh, edge_axes: Sequence[str]) -> int:
+    n = 1
+    for a in edge_axes:
+        n *= mesh.shape[a]
+    return n
+
+
+def shard_edges(n_edges: int, mesh: jax.sharding.Mesh,
+                edge_axes: Sequence[str]) -> int:
+    """Edges one shard sweeps: the list padded to split evenly over the
+    shards of ``edge_axes``, one contiguous block each."""
+    return max(-(-n_edges // _n_shards(mesh, edge_axes)), 1)
 
 
 @functools.partial(
@@ -105,9 +115,7 @@ def _distributed_fixpoint(
     single-device ``active_m0`` path).
     """
     axis = tuple(edge_axes)
-    n_shards = 1
-    for a in axis:
-        n_shards *= mesh.shape[a]
+    n_shards = _n_shards(mesh, axis)
     m = src.shape[0]
     n = L0.shape[0]
     m_loc = m // n_shards
@@ -138,7 +146,10 @@ def _distributed_fixpoint(
                 return jax.lax.pmin(L, axis)
 
         def all_shards_ok(ok_local):
-            return jnp.bool_(jax.lax.pmin(ok_local.astype(jnp.int32), axis))
+            # the round's second collective: the test AND-reduced over shards
+            with jax.named_scope(tracing.CONVERGE_CHECK):
+                return jnp.bool_(
+                    jax.lax.pmin(ok_local.astype(jnp.int32), axis))
 
         if not adaptive:
             def cond(s: _State):
@@ -248,10 +259,8 @@ def distributed_contour(
     elif not 0 <= n_active <= graph.n_edges:
         raise ValueError(f"n_active={n_active} outside [0, "
                          f"{graph.n_edges}]")
-    n_shards = 1
-    for a in edge_axes:
-        n_shards *= mesh.shape[a]
-    g = graph.pad_edges(_round_up(max(graph.n_edges, n_shards), n_shards))
+    g = graph.pad_edges(shard_edges(graph.n_edges, mesh, edge_axes)
+                        * _n_shards(mesh, edge_axes))
     axis = tuple(edge_axes)
     edge_spec = P(axis if len(axis) > 1 else axis[0])
     lbl_spec = P()
@@ -313,14 +322,16 @@ def distributed_contour_step_fn(
                 L = lab.pointer_jump(L, rounds=1)
             with jax.named_scope(tracing.LABEL_ALLREDUCE):
                 L = jax.lax.pmin(L, axis)
-            if check_every <= 1:
+
+            def do_check(_):
                 ok_local = lab.converged_early(L, src_loc, dst_loc)
-                ok = jnp.bool_(jax.lax.pmin(ok_local.astype(jnp.int32), axis))
-            else:
-                def do_check(_):
-                    ok_local = lab.converged_early(L, src_loc, dst_loc)
+                with jax.named_scope(tracing.CONVERGE_CHECK):
                     return jnp.bool_(
                         jax.lax.pmin(ok_local.astype(jnp.int32), axis))
+
+            if check_every <= 1:
+                ok = do_check(None)
+            else:
                 ok = jax.lax.cond(
                     (s.it + 1) % check_every == 0, do_check,
                     lambda _: jnp.array(False), operand=None)

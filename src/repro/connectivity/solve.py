@@ -213,8 +213,10 @@ def solve(
                 # demote this size bucket to XLA in the tuning cache — with a
                 # TTL, so the failed backend is retried/retuned later instead
                 # of being pinned out forever
+                from repro.connectivity.solvers import sweep_edges
                 _planner.record_kernel_failure(
-                    graph.n_vertices, graph.n_edges, failed_backend=backend)
+                    graph.n_vertices, sweep_edges(graph.n_edges, opts),
+                    failed_backend=backend)
             except Exception:
                 pass  # a cache write must never break the degradation path
             retry_opts = opts.replace(backend="xla", plan=None)

@@ -41,8 +41,18 @@ from repro.graphs.generators import ArrayChunks
 _OOCORE_NAMES = ("oocore", "out_of_core")
 
 
+def sweep_edges(n_edges: int, opts) -> int:
+    """Edges one sweep of a solve of ``n_edges`` edges covers: all of them
+    on one device; on a mesh (``opts.mesh``), where each device sweeps
+    only its own, one shard's block of them."""
+    if getattr(opts, "mesh", None) is None:
+        return n_edges
+    return _distributed.shard_edges(n_edges, opts.mesh, opts.edge_axes)
+
+
 def resolve_backend_plan(n_vertices: int, n_edges: int, opts):
-    """Concrete (backend, plan) for a solve.
+    """Concrete (backend, plan) for a solve of ``n_edges`` edges, keyed
+    on the edges one sweep covers (:func:`sweep_edges`).
 
     Resolution goes through the execution-plan layer
     (:func:`repro.connectivity.planner.resolve_plan`): a plan pinned in
@@ -54,6 +64,7 @@ def resolve_backend_plan(n_vertices: int, n_edges: int, opts):
     the plan additionally carries the VMEM-derived streaming chunk
     bucket (``chunk_bucket``), unless the pinned plan already set one.
     """
+    n_edges = sweep_edges(n_edges, opts)
     plan = _planner.resolve_plan(n_vertices, n_edges, backend=opts.backend,
                                  plan=opts.plan)
     backend = plan.backend if opts.backend == "auto" else opts.backend

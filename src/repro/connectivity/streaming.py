@@ -76,7 +76,7 @@ from repro.connectivity.options import SolveOptions
 from repro.connectivity.result import ComponentResult
 from repro.connectivity.solve import _resolve, make_result, \
     resolve_warm_start, solve
-from repro.connectivity.solvers import resolve_backend_plan
+from repro.connectivity.solvers import resolve_backend_plan, sweep_edges
 from repro.graphs.structs import Graph
 from repro.runtime.recovery import FaultInjector, is_transient_error
 
@@ -464,7 +464,7 @@ class StreamingConnectivity:
                 # TTL'd demotion: later batches (and later streams) in
                 # this size bucket resolve straight to XLA until it lapses
                 _planner.record_kernel_failure(
-                    self._n_cap, pad_k,
+                    self._n_cap, sweep_edges(pad_k, self._opts),
                     failed_backend=self._opts.backend)
             except Exception:
                 pass  # cache writes must never break the fallback

@@ -28,7 +28,8 @@ Every caller of the scoped function inherits the scope: ``solve()``,
   ``fused_relax`` (the fused single-tile relabel + scatter-min pass) and
   ``scatter_min`` (the XLA scatter-min sweep).
 * ``pointer_jump``: label compression ``L <- min(L, L[L])``.
-* ``converge_check``: the early-convergence test (paper §III-B2).
+* ``converge_check``: the early-convergence test (paper §III-B2); on a
+  mesh, the shards' AND-reduce of it too.
 * ``compact``: frontier contraction of the active edges.
 * ``compress``: the post-loop compression to a star forest.
 * ``sampling_prep``: the sampling strategy's edge permutation.

@@ -4,6 +4,7 @@ Multi-device coverage runs in a subprocess with
 XLA_FLAGS=--xla_force_host_platform_device_count=8 (per the assignment,
 the test process itself must keep seeing 1 device).
 """
+import json
 import os
 import subprocess
 import sys
@@ -129,3 +130,51 @@ def test_distributed_frontier_8way_subprocess():
                          timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "FRONTIER_SUBPROCESS_OK" in out.stdout
+
+
+# A solve over four devices with the planner's plan resolution spied on:
+# prints the edge counts it was keyed on, the graph's, and its provenance.
+_PLAN_KEY_SUBPROCESS_BODY = textwrap.dedent("""
+    import json, os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import numpy as np
+    import repro
+    from repro import jax_compat
+    from repro.connectivity import planner
+    from repro.graphs.oracle import connected_components_oracle
+
+    keyed = []
+    real = planner.resolve_plan
+
+    def spy(n_vertices, m_edges, **kw):
+        keyed.append(m_edges)
+        return real(n_vertices, m_edges, **kw)
+
+    planner.resolve_plan = spy
+    rng = np.random.default_rng(7)
+    n, m = 700, 1001            # 1001 edges pad to 4 shards of 251
+    g = repro.Graph(src=rng.integers(0, n, m).astype(np.int32),
+                    dst=rng.integers(0, n, m).astype(np.int32),
+                    n_vertices=n)
+    res = repro.solve(g, mesh=jax_compat.make_mesh((4,), ("data",)))
+    oracle = connected_components_oracle(*g.to_numpy())
+    assert (np.asarray(res.labels) == oracle).all()
+    print(json.dumps({"keyed": keyed, "provenance": list(res.provenance),
+                      "shard_plan": real(n, 251).provenance_entry()}))
+""")
+
+
+def test_sharded_plan_is_keyed_on_the_shards_edges():
+    """Each device sweeps only its own block of the edges, so the plan of
+    a solve over a mesh is resolved for one shard's block: 1001 edges
+    over four devices pad to 1004, 251 a shard."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, "-c", _PLAN_KEY_SUBPROCESS_BODY],
+                         capture_output=True, text=True, env=env,
+                         timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    assert got["keyed"] and set(got["keyed"]) == {251}
+    assert got["shard_plan"] in got["provenance"]

@@ -1,7 +1,12 @@
 """The program's trace names: device scopes in every lowered program, host
 spans around ``solve()`` and ``ingest()`` in a recorded trace."""
 import glob
+import json
+import os
 import re
+import subprocess
+import sys
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +22,7 @@ from repro.connectivity.planner import heuristic_plan
 from repro.graphs import generators as gen
 
 N, M = 300, 1200
+SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _locations(lowered) -> set:
@@ -116,6 +122,67 @@ def test_sharded_solve_scopes_its_label_allreduce():
         backend="xla", plan=None, sampling=0, compact_every=0))
     assert _under(locs, tracing.LABEL_ALLREDUCE)
     assert _under(locs, tracing.MM_SWEEP, tracing.SCATTER_MIN)
+
+
+# Compiles the sharded solve's programs for a mesh of four CPU devices and
+# prints the op_name of every all-reduce in their HLO, one JSON list per
+# program: _distributed_fixpoint as solve(graph, mesh=...) calls it by
+# default, and distributed_contour_step_fn with the test every round and
+# every other round.
+_SHARDED_ALL_REDUCES = textwrap.dedent("""
+    import json, os, re
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro import jax_compat
+    from repro.connectivity.distributed import (
+        _distributed_fixpoint, distributed_contour_step_fn)
+    from repro.connectivity.planner import resolve_plan
+
+    mesh = jax_compat.make_mesh((4,), ("data",))
+    n, m = 300, 1200
+    edges = NamedSharding(mesh, P("data"))
+    rep = NamedSharding(mesh, P())
+    src = jax.device_put(jnp.arange(m, dtype=jnp.int32) % n, edges)
+    dst = jax.device_put(jnp.arange(m, dtype=jnp.int32)[::-1] % n, edges)
+    plan = resolve_plan(n, m // 4)
+    programs = [_distributed_fixpoint.lower(
+        src, dst, jax.device_put(jnp.arange(n, dtype=jnp.int32), rep),
+        jax.device_put(jnp.int32(m), rep), mesh=mesh, edge_axes=("data",),
+        local_rounds=1, max_iters=100, async_compress=1,
+        backend=plan.backend, plan=plan, sampling=0, compact_every=0)]
+    programs += [distributed_contour_step_fn.lower(
+        src, dst, n, mesh, check_every=k) for k in (1, 2)]
+    for lowered in programs:
+        hlo = lowered.compile().as_text()
+        print(json.dumps([
+            (re.search(r'op_name="([^"]*)"', line) or [None, None])[1]
+            for line in hlo.splitlines()
+            if " all-reduce(" in line or " all-reduce-start(" in line]))
+""")
+
+
+def test_every_all_reduce_of_a_sharded_solve_is_scoped():
+    """On a mesh of four devices, the round's two collectives, the label
+    ``pmin`` and the shards' AND-reduce of the convergence test, each lie
+    under a scope: no collective of the round goes unattributed."""
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        filter(None, [SRC, os.environ.get("PYTHONPATH")])))
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, "-c", _SHARDED_ALL_REDUCES],
+                         capture_output=True, text=True, env=env,
+                         timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    programs = [json.loads(line) for line in out.stdout.splitlines()
+                if line.startswith("[")]
+    assert len(programs) == 3
+    for names in programs:
+        assert names, "no all-reduce in the sharded program"
+        for name in names:
+            assert name and (f"/{tracing.LABEL_ALLREDUCE}/" in name
+                             or f"/{tracing.CONVERGE_CHECK}/" in name), name
+        assert any(f"/{tracing.LABEL_ALLREDUCE}/" in n for n in names)
+        assert any(f"/{tracing.CONVERGE_CHECK}/" in n for n in names)
 
 
 def test_every_scope_and_span_is_named_once():
